@@ -333,6 +333,8 @@ def _mesh_rows():
     device count must precede jax init, and this process's single-device
     rows must keep their real backend for run-to-run comparability. The
     child asserts shard_map/unrolled bitwise parity before timing."""
+    from repro.launch.env import require_cpu_parent
+    require_cpu_parent("the mesh rows")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     repo = pathlib.Path(__file__).resolve().parent.parent

@@ -141,6 +141,7 @@ def _replica_fleet(n_replicas, *, arch, cim, requests, slots, chunk,
     group when the host has cores to back every rank, sequential solo
     replicas otherwise (see module docstring)."""
     from repro.launch import env as lenv
+    lenv.require_cpu_parent("the replica scaling rows")
     concurrent = n_replicas > 1 and \
         len(os.sched_getaffinity(0)) >= 2 * n_replicas
     coord = f"localhost:{lenv.free_port()}" if concurrent else ""

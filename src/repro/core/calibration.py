@@ -16,6 +16,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import CIMConfig
 from ..kernels.cim_mvm.ref import cim_mvm_ref
@@ -26,16 +27,19 @@ class LayerCalibration(NamedTuple):
     adc_offset: jax.Array   # (C,) volts measured with zero input, to cancel
 
 
-def calibrate_v_decr(q_samples, cfg: CIMConfig, coverage: float = 0.999):
-    """Pick v_decr so `coverage` of |Q| falls inside the N_max counts."""
-    qmax = jnp.quantile(jnp.abs(q_samples), coverage)
+def calibrate_v_decr(q_samples, cfg: CIMConfig, coverage: float = 0.999,
+                     axis=None):
+    """Pick v_decr so `coverage` of |Q| falls inside the N_max counts
+    (over `axis`; None pools every sample)."""
+    qmax = jnp.quantile(jnp.abs(q_samples), coverage, axis=axis)
     return jnp.maximum(qmax, 1e-9) / cfg.out_mag_levels
 
 
-def tile_partial_sums(x_int, g_pos, g_neg, tile, cfg: CIMConfig,
+def tile_partial_sums(x_int, g_pos, g_neg, tiles, cfg: CIMConfig,
                       direction: str = "fwd"):
-    """Normalized analog partial sums ONE core (tile) produces on a batch —
-    the distribution its ADC operating point must cover.
+    """Normalized analog partial sums that cores (tiles) of ONE extent
+    produce on a batch — the distributions their ADC operating points must
+    cover. Returns (T, B, out) for the T tiles, all gathered at once.
 
     The TNSA reads the same programmed cells in either direction, and the
     two directions see DIFFERENT distributions (different summed wire count
@@ -48,20 +52,33 @@ def tile_partial_sums(x_int, g_pos, g_neg, tile, cfg: CIMConfig,
             its rows; normalizer = per-row sum of G+ + G-.
 
     x_int: (B, R) / (B, C) integer activations in the direction's input
-    space (full-matrix coordinates; the tile's slice is taken here).
+    space (full-matrix coordinates; each tile's slice is taken here).
     """
-    xf = x_int.astype(jnp.float32)
-    gp = g_pos[tile.row0:tile.row0 + tile.rows,
-               tile.col0:tile.col0 + tile.cols]
-    gn = g_neg[tile.row0:tile.row0 + tile.rows,
-               tile.col0:tile.col0 + tile.cols]
+    rows, cols = tiles[0].rows, tiles[0].cols
+    if any((t.rows, t.cols) != (rows, cols) for t in tiles):
+        raise ValueError("tile_partial_sums takes tiles of one extent")
+    r0 = jnp.asarray([t.row0 for t in tiles], jnp.int32)
+    c0 = jnp.asarray([t.col0 for t in tiles], jnp.int32)
+    block = jax.vmap(lambda a, r, c: jax.lax.dynamic_slice(
+        a, (r, c), (rows, cols)), in_axes=(None, 0, 0))
+    gp, gn = block(g_pos, r0, c0), block(g_neg, r0, c0)
     gd = gp - gn
+    xf = x_int.astype(jnp.float32)
+
+    def take(offsets, n):
+        # (T, B, n) input slices: slice each distinct offset once, then
+        # index whole slabs (a per-element gather is slow on CPU)
+        starts = sorted(set(offsets))
+        slabs = jnp.stack([xf[:, s:s + n] for s in starts])
+        return slabs[np.searchsorted(starts, offsets)]
+
     if direction == "fwd":
-        return (xf[:, tile.row0:tile.row0 + tile.rows] @ gd) \
-            * cfg.v_read / jnp.sum(gp + gn, axis=0)
+        return (take([t.row0 for t in tiles], rows) @ gd) * cfg.v_read \
+            / jnp.sum(gp + gn, axis=1)[:, None, :]
     if direction == "bwd":
-        return (xf[:, tile.col0:tile.col0 + tile.cols] @ gd.T) \
-            * cfg.v_read / jnp.sum(gp + gn, axis=1)
+        return (take([t.col0 for t in tiles], cols)
+                @ jnp.swapaxes(gd, 1, 2)) * cfg.v_read \
+            / jnp.sum(gp + gn, axis=2)[:, None, :]
     raise ValueError(f"direction must be 'fwd' or 'bwd', got {direction!r}")
 
 

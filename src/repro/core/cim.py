@@ -58,6 +58,7 @@ from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import CIMConfig, CoreSpec
 from .quant import quantize_to_int
@@ -211,14 +212,18 @@ def calibrate_tile_v_decr(layer: CIMLayer, tiles, x_cal, cfg: CIMConfig,
     """
     alpha = layer.in_alpha if in_alpha is None else in_alpha
     x_int, _ = quantize_to_int(x_cal, alpha, cfg.in_bits, signed=True)
-    vds = []
-    for t in tiles:
-        if t.replica:
-            continue
-        q = tile_partial_sums(x_int, layer.g_pos, layer.g_neg, t, cfg,
-                              direction)
-        vds.append(calibrate_v_decr(q, cfg, coverage))
-    return jnp.stack(vds)
+    tiles = [t for t in tiles if not t.replica]
+    # one batched pass per tile extent (a handful per layer), not per tile
+    by_extent: Dict[Tuple[int, int], list] = {}
+    for i, t in enumerate(tiles):
+        by_extent.setdefault((t.rows, t.cols), []).append(i)
+    parts, where = [], []
+    for idx in by_extent.values():
+        q = tile_partial_sums(x_int, layer.g_pos, layer.g_neg,
+                              [tiles[i] for i in idx], cfg, direction)
+        parts.append(calibrate_v_decr(q, cfg, coverage, axis=(1, 2)))
+        where += idx
+    return jnp.concatenate(parts)[np.argsort(where)]
 
 
 def pack_cim_layer(layer: CIMLayer, tiles, cfg: CIMConfig, v_decr=None,

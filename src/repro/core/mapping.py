@@ -78,6 +78,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import CIMConfig, CoreSpec
 
@@ -504,55 +505,52 @@ def pack_tiles(tiles: Sequence[Tile], gd, *, gsum=None, v_decr=1.0,
     n_rows = max(t.row0 + t.rows for t in tiles)
     n_cols = max(t.col0 + t.cols for t in tiles)
 
-    gd = jnp.asarray(gd, jnp.float32)
-    zero_blk = jnp.zeros((bk, bn), jnp.float32)
-    zero_col = jnp.zeros((bn,), jnp.float32)
-    gd_tiles, inv_tiles, den_tiles, vd_slots = [], [], [], []
-    row_block, col_block, slot_pass = [], [], []
-    for si, idx in enumerate(order):
-        if idx is None:                       # idle slot: a core sits out
-            gd_tiles.append(zero_blk)
-            inv_tiles.append(zero_col)
-            den_tiles.append(zero_col)        # accumulates exactly zero
-            vd_slots.append(jnp.asarray(1.0, jnp.float32))
-            row_block.append(0)
-            col_block.append(0)
-            slot_pass.append(si // pass_len)
-            continue
-        t = tiles[idx]
-        blk = zero_blk.at[:t.rows, :t.cols].set(
-            jax.lax.dynamic_slice(gd, (t.row0, t.col0), (t.rows, t.cols)))
-        gd_tiles.append(blk)
-        mask = zero_col.at[:t.cols].set(1.0)
-        if gsum is None:
-            inv = mask                       # normalizer 1 on valid columns
-            norm = mask
-        else:
-            norm_t = jnp.sum(jax.lax.dynamic_slice(
-                gsum, (t.row0, t.col0), (t.rows, t.cols)), axis=0)
-            norm = zero_col.at[:t.cols].set(norm_t)
-            inv = jnp.where(norm > 0, 1.0 / jnp.maximum(norm, 1e-30), 0.0)
-        den_tiles.append((mask * norm * v_decr[idx]) if fold_norm else mask)
-        inv_tiles.append(inv)
-        vd_slots.append(v_decr[idx])
-        row_block.append(t.row0 // bk)
-        col_block.append(t.col0 // bn)
-        slot_pass.append(si // pass_len)
+    # per-slot geometry (idle slots: an empty extent -> zero tile)
+    live = [i is not None for i in order]
+    pick = lambda f: np.array([f(tiles[i]) if i is not None else 0
+                               for i in order], np.int32)
+    r0, c0 = pick(lambda t: t.row0), pick(lambda t: t.col0)
+    rows, cols = pick(lambda t: t.rows), pick(lambda t: t.cols)
+    col_ok = np.arange(bn)[None, :] < cols[:, None]                # (T, bn)
+    cell_ok = (np.arange(bk)[None, :, None] < rows[:, None, None]) \
+        & col_ok[:, None, :]                                       # (T,bk,bn)
+    gd_tiles = jnp.where(cell_ok, _gather_blocks(gd, r0, c0, bk, bn), 0.0)
+    mask = jnp.asarray(col_ok, jnp.float32)
+    if gsum is None:
+        inv = norm = mask                   # normalizer 1 on valid columns
+    else:
+        norm = jnp.sum(jnp.where(cell_ok, _gather_blocks(gsum, r0, c0,
+                                                         bk, bn), 0.0),
+                       axis=1)
+        inv = jnp.where(norm > 0, 1.0 / jnp.maximum(norm, 1e-30), 0.0)
+    vd_slots = jnp.where(jnp.asarray(live),
+                         v_decr[np.array([i or 0 for i in order])], 1.0)
+    den = (mask * norm * vd_slots[:, None]) if fold_norm else mask
 
     return PackedPlan(
         layer=tiles[0].layer, bk=bk, bn=bn, n_rows=n_rows, n_cols=n_cols,
-        row_block=tuple(row_block),
-        col_block=tuple(col_block),
-        seq_slot=tuple(slot_pass),
+        row_block=tuple(int(r) // bk for r in r0),
+        col_block=tuple(int(c) // bn for c in c0),
+        seq_slot=tuple(si // pass_len for si in range(len(order))),
         n_passes=n_passes,
         transpose=False,
         tile_slot=tuple(range(len(order))),
         out_slot=out_slot,
         out_col=out_col,
-        gd_tiles=jnp.stack(gd_tiles),
-        inv_norm_tiles=jnp.stack(inv_tiles)[:, None, :],
-        v_decr_tiles=jnp.stack(vd_slots),
-        denorm_tiles=jnp.stack(den_tiles)[:, None, :])
+        gd_tiles=gd_tiles,
+        inv_norm_tiles=inv[:, None, :],
+        v_decr_tiles=vd_slots,
+        denorm_tiles=den[:, None, :])
+
+
+def _gather_blocks(a, r0, c0, bk: int, bn: int):
+    """(T, bk, bn) windows of the 2-D `a` at offsets (r0[t], c0[t]), read
+    past the matrix edge as zeros — one gather for a whole layer's tiles
+    instead of one slice per tile (thousands of dispatches at published
+    widths)."""
+    a = jnp.pad(jnp.asarray(a, jnp.float32), ((0, bk), (0, bn)))
+    return jax.vmap(lambda r, c: jax.lax.dynamic_slice(a, (r, c), (bk, bn)))(
+        jnp.asarray(r0), jnp.asarray(c0))
 
 
 def pack_tiles_transposed(tiles: Sequence[Tile], packed: PackedPlan, *,

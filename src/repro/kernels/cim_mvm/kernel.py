@@ -77,6 +77,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..prng import hash_uniform
 
+# The analog charge is an f32 quantity: the TPU's default f32 matmul runs
+# at reduced (bf16-pass) precision, which moved 55% of a codeqwen wq
+# layer's summed ADC counts (by up to 6) against the f32 oracle on a v5e.
+# HIGHEST keeps the contraction in f32 there; on the CPU interpreter it
+# changes nothing.
+_F32 = jax.lax.Precision.HIGHEST
+
 # Trace counters (incremented while jit TRACES each wrapper, not per call):
 # tests and benchmarks assert "one compiled dispatch per plan shape" with
 # these. Keyed by kernel name.
@@ -143,7 +150,7 @@ def _cim_kernel(x_ref, gd_ref, invn_ref, vd_ref, seed_ref, out_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], gd_ref[...],
+    acc_ref[...] += jnp.dot(x_ref[...], gd_ref[...], precision=_F32,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -224,7 +231,7 @@ def _cim_packed_kernel(row_ref, col_ref, x_ref, gd_ref, invn_ref, den_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    q = jnp.dot(x_ref[...], gd_ref[0],
+    q = jnp.dot(x_ref[...], gd_ref[0], precision=_F32,
                 preferred_element_type=jnp.float32) * v_read * invn_ref[0]
     counts = _epilogue(q, vd_ref[t], activation, n_max, seed_ref,
                        ij=(pl.program_id(0), t))
@@ -317,7 +324,7 @@ def _cim_sched_kernel(row_ref, outs_ref, x_ref, gd_ref, invn_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    q = jnp.dot(x_ref[...], gd_ref[0],
+    q = jnp.dot(x_ref[...], gd_ref[0], precision=_F32,
                 preferred_element_type=jnp.float32) * v_read * invn_ref[0]
     counts = _epilogue(q, vd_ref[t], activation, n_max, seed_ref,
                        ij=(pl.program_id(0), t))
@@ -453,6 +460,7 @@ def _cim_transposed_kernel(in_ref, stk_ref, outs_ref, x_ref, gd_ref, invn_ref,
 
     q = jax.lax.dot_general(
         x_ref[...], gd_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_F32,
         preferred_element_type=jnp.float32) * v_read * invn_ref[0]
     counts = _epilogue(q, vd_ref[t], activation, n_max, seed_ref,
                        ij=(pl.program_id(0), stk_ref[t]))

@@ -27,10 +27,12 @@ The batch block shape defaults to the autotuner's cached winner for the
 plan's signature (`autotune.lookup`; 256 until `autotune.tune` has measured
 the shape) — pass bm explicitly to pin it.
 
-On this CPU container the kernels run in interpret mode; on TPU set
-interpret=False (default chosen from backend).
+interpret=None picks the mode from the backend (`default_interpret`):
+interpreted on CPU, compiled on TPU, an error anywhere else.
 """
 from __future__ import annotations
+
+from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
@@ -38,11 +40,23 @@ import jax.numpy as jnp
 from . import autotune
 from .kernel import (cim_mvm_pallas, cim_mvm_packed_pallas,
                      cim_mvm_scheduled_pallas, cim_mvm_transposed_pallas)
-from ...core.types import CIMConfig
+
+if TYPE_CHECKING:      # see ref.py: no core import while kernels load
+    from ...core.types import CIMConfig
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def default_interpret() -> bool:
+    """Interpret mode on the CPU backend, compiled Mosaic kernels on TPU.
+    Any other backend raises: silently interpreting there would report
+    interpreter numbers as device results."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"{backend!r} backend is neither (set JAX_PLATFORMS=cpu to test)")
 
 
 def cim_mvm(x_int, g_pos, g_neg, v_decr, cfg: CIMConfig, *, seed=0,
@@ -53,7 +67,7 @@ def cim_mvm(x_int, g_pos, g_neg, v_decr, cfg: CIMConfig, *, seed=0,
     g_pos/g_neg: (R, C) conductances in uS.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     gd = (g_pos - g_neg).astype(jnp.float32)
     if norm is None:
         norm = jnp.sum(g_pos + g_neg, axis=0)
@@ -92,7 +106,7 @@ def packed_call(x, packed, *, activation: str, n_max: int, v_read: float,
             f"input has {x.shape[-1]} features but plan "
             f"'{packed.layer}' covers {packed.n_rows} weight rows")
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     if bm is None:
         bm = autotune.lookup(packed, x.shape[0], activation)
     n_slots = packed.n_tiles

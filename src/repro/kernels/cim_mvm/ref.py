@@ -22,14 +22,13 @@ use the smooth surrogates in repro/core instead.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import TYPE_CHECKING, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ...core.types import CIMConfig
-from ...core.quant import int_bit_planes
-from ...core.noise import lfsr_noise
+if TYPE_CHECKING:      # kernels import nothing from core at module level:
+    from ...core.types import CIMConfig   # core.cim imports this module
 
 
 class CIMOutput(NamedTuple):
@@ -78,6 +77,7 @@ def adc_convert(q, cfg: CIMConfig, v_decr, *, key=None):
         return out.astype(jnp.int32)
     if cfg.activation == "stochastic":
         assert key is not None, "stochastic activation needs a PRNG key"
+        from ...core.noise import lfsr_noise
         noise = lfsr_noise(key, q.shape, v_decr * n_max)
         return (q + noise > 0).astype(jnp.int32)
     # "none": plain signed quantization
@@ -121,6 +121,7 @@ def cim_mvm_ref(
         return v_out
 
     if bit_serial:
+        from ...core.quant import int_bit_planes
         planes = int_bit_planes(x_int, cfg.in_mag_bits)           # (K, B, R)
         weights = 2 ** jnp.arange(cfg.in_mag_bits - 1, -1, -1, dtype=jnp.float32)
         v_phases = jax.vmap(settle)(planes)                       # (K, B, C)

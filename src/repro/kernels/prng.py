@@ -33,8 +33,12 @@ def hash_bits(shape, *salts):
 
 
 def hash_uniform(shape, *salts):
-    """Uniform in [0, 1)."""
-    return hash_bits(shape, *salts).astype(jnp.float32) * (1.0 / 4294967296.0)
+    """Uniform in [0, 1) on a 2^-24 grid: the top 24 hash bits go through
+    int32 to float32 (exact — f32 has a 24-bit significand). Mosaic has no
+    uint32 -> float32 cast, so this form is what lets the stochastic
+    kernel epilogue and the noisy matmul compile on TPU."""
+    top = (hash_bits(shape, *salts) >> 8).astype(jnp.int32)
+    return top.astype(jnp.float32) * (1.0 / 16777216.0)
 
 
 def hash_normal(shape, *salts):

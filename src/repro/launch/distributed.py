@@ -43,10 +43,8 @@ from __future__ import annotations
 import json
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from . import env as _env
-from .mesh import mesh_shape_for
+from .mesh import make_mesh, mesh_shape_for
 
 _INITIALIZED = False
 
@@ -95,11 +93,10 @@ def serving_mesh(max_model: int = 16):
     (`distributed/sharding.pool_pspecs`); the cross-process data axis is
     process replication (see `global_mesh_shape`)."""
     import jax
-    from jax.sharding import Mesh
     local = jax.local_devices()
     shape = mesh_shape_for(len(local), max_model)
-    devs = np.array(local).reshape(shape["data"], shape["model"])
-    return Mesh(devs, ("data", "model"))
+    return make_mesh((shape["data"], shape["model"]), ("data", "model"),
+                     devices=local)
 
 
 def global_mesh_shape(max_model: int = 16) -> Dict[str, int]:

@@ -23,8 +23,17 @@ group init), and the module doubles as a CLI launcher:
         -- python -m repro.launch.serve --smoke --cim --traffic ...
 
 Everything after `--` is the per-rank command. This module deliberately
-never imports jax: the parent must stay device-free so children own
-their backends.
+never imports jax at module level: the parent must stay device-free so
+children own their backends.
+
+`--host-devices` forces CPU host-platform devices and is CPU-only. On an
+accelerator a chip belongs to one process at a time: a parent that has
+touched JAX holds it, and a child that needs it then fails or hangs, so
+multi-device work on a TPU host runs in ONE process over all its chips.
+
+`enable_compile_cache` is the one place the persistent compilation cache
+is configured; every entry point (serve, train, chip_smoke.py) calls it
+first.
 """
 from __future__ import annotations
 
@@ -40,6 +49,26 @@ ENV_NUM_PROCESSES = "REPRO_NUM_PROCESSES"
 ENV_PROCESS_ID = "REPRO_PROCESS_ID"
 
 DEFAULT_COORD_PORT = 46223
+
+# <repo>/.jax_cache — a fixed path: the cache key includes it, so a cache
+# that moves with a temporary directory never hits. Git-ignored.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. If JAX_COMPILATION_CACHE_DIR is set, JAX already reads it
+    and nothing else is set; otherwise the cache lives at
+    COMPILE_CACHE_DIR. Only sets a config flag — the backend is not
+    started, so callers may still pick devices afterwards."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def xla_flags(host_devices: Optional[int] = None,
@@ -110,6 +139,21 @@ def from_env(environ: Optional[Dict[str, str]] = None
         raise RuntimeError(f"bad multi-process environment: "
                            f"num_processes={n} process_id={pid}")
     return coord, n, pid
+
+
+def require_cpu_parent(what: str) -> None:
+    """Raise unless this process runs JAX on the CPU. For a parent that
+    has started JAX and is about to spawn children that need the device:
+    on an accelerator the parent already holds the chip, and the children
+    would fail or hang."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} spawns child processes that need the device, but this "
+            f"process already holds the {backend!r} backend (a chip belongs "
+            "to one process at a time); it runs on CPU host devices only "
+            "(JAX_PLATFORMS=cpu)")
 
 
 def free_port() -> int:

@@ -1,9 +1,27 @@
-"""Production mesh builders. Functions (not module-level constants) so that
+"""Production meshes. Functions (not module-level constants) so that
 importing never touches jax device state — dryrun.py sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init."""
+XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init.
+
+`make_mesh` is the one place a Mesh is constructed; every other mesh
+function here and in launch/distributed goes through it."""
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """A Mesh of `shape` over `devices` (default: all devices) with AUTO
+    axis types. jax.make_mesh defaults to Explicit axes, under which every
+    serving jit must carry sharding-typed avals — the KV-cache
+    dynamic_update_slice then raises ShardingTypeError. The serving and
+    training steps rely on GSPMD propagation, i.e. Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     inter-pod links — kept to one collective per step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:
@@ -67,5 +85,4 @@ def serving_mesh(max_model: int = 16):
     exactly one collective per projection (psum for row-parallel partial
     sums, the out-spec all-gather for column-parallel slices)."""
     shape = serving_mesh_shape(max_model)
-    return jax.make_mesh((shape["data"], shape["model"]),
-                         ("data", "model"))
+    return make_mesh((shape["data"], shape["model"]), ("data", "model"))

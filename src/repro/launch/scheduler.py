@@ -237,6 +237,13 @@ class ContinuousBatchingEngine:
         """Compiled-trace count of the pool decode step (contract: 1)."""
         return self._decode._cache_size()
 
+    def decode_hlo(self) -> str:
+        """Optimized HLO text of the pool decode step as compiled for the
+        current params and pool — where a device check looks for its
+        kernels (a persistent compilation cache makes this a cache hit)."""
+        return self._decode.jitted.lower(self.params, self.pool) \
+            .compile().as_text()
+
     def _chunks(self, prompt: np.ndarray) -> List[np.ndarray]:
         c = self.chunk
         return [prompt[i:i + c] for i in range(0, len(prompt), c)]

@@ -4,6 +4,14 @@
       --batch 4 --prompt-len 64 --gen 32
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --smoke \
       --cim --traffic --requests 8 --slots 4
+  python -m repro.launch.serve --arch codeqwen1.5-7b --layers 2 --cim \
+      --cim-cores 8192 --traffic      # published widths, 2 of 32 layers
+
+--layers N keeps the first N layers of a config and every width: the
+depth cut for serving a published-width model on one accelerator.
+`main` returns the generated tokens in static mode and a `TrafficRun`
+(stats including deploy seconds, the engine, the requests) in --traffic
+mode.
 
 Two serving modes share one compiled chip stack (weight-stationary: the
 same programmed conductances serve every request):
@@ -49,7 +57,8 @@ the out-spec all-gather; the prefill/decode jits close over the mesh via
 cfg.cim_mesh. 'off' keeps the documented single-process unrolled shard
 loop (nn.sharded_packed_loop, the parity oracle); 'DxM' (e.g. '1x8')
 forces an explicit (data, model) mesh shape. On one device both modes
-collapse to the same single-dispatch path. Multi-device CPU smoke:
+collapse to the same single-dispatch path. Every mesh comes from
+launch/mesh.make_mesh (Auto axes). Multi-device CPU smoke:
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (tools/ci.sh).
 --cim-ir-drop > 0 turns on the IR-drop planning constraint (vertical
 column splits); --cim-cores shrinks the per-chip core budget to force
@@ -80,6 +89,7 @@ from __future__ import annotations
 
 import argparse
 import json
+from typing import Any, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -142,10 +152,22 @@ def _write_obs(args, metrics, trace=None, summary=None, extra_labels=None):
         print(f"summary: wrote {args.summary_out}")
 
 
+class TrafficRun(NamedTuple):
+    """What `main` returns in --traffic mode: the run's summary stats, the
+    engine that served it (params, pool, compiled steps) and this rank's
+    requests with their tokens — and logits rows under --capture-logits."""
+    stats: dict
+    engine: Any
+    requests: List
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-9b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve only the first N layers of the config: a "
+                         "depth cut, every width unchanged (0 = full depth)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
@@ -163,6 +185,10 @@ def main(argv=None):
                          "bitwise vs one-shot)")
     ap.add_argument("--rate", type=float, default=50.0,
                     help="--traffic: Poisson arrival rate (req/s)")
+    ap.add_argument("--capture-logits", action="store_true",
+                    help="--traffic: keep every request's logits rows on "
+                         "the host (one device-to-host copy per token; for "
+                         "checks against a reference, not for timing)")
     ap.add_argument("--cim", action="store_true",
                     help="serve dense-block projections through the packed "
                          "CIM engine (programs the chip before serving)")
@@ -188,6 +214,8 @@ def main(argv=None):
                          "(e.g. '1x8') forces a (data, model) shape")
     _add_obs_flags(ap)
     args = ap.parse_args(argv)
+    from .env import enable_compile_cache
+    enable_compile_cache()
 
     # join the process group (if any) BEFORE the first device query —
     # jax.distributed must initialize ahead of backend topology pinning
@@ -197,6 +225,14 @@ def main(argv=None):
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     cfg = cfg.replace(dtype=jnp.float32 if args.smoke else cfg.dtype)
+    if args.layers:
+        if not 1 <= args.layers <= cfg.n_layers:
+            ap.error(f"--layers must be in 1..{cfg.n_layers}, "
+                     f"got {args.layers}")
+        print(f"depth: serving {args.layers} of {cfg.n_layers} layers "
+              f"(d_model={cfg.d_model}, d_ff={cfg.d_ff}, "
+              f"vocab={cfg.vocab} unchanged)")
+        cfg = cfg.replace(n_layers=args.layers)
     mesh = None
     if args.cim:
         cfg = cfg.replace(cim_mode="packed", dtype=jnp.float32,
@@ -220,24 +256,21 @@ def main(argv=None):
                 mesh = serving_mesh()
         elif args.cim_mesh != "off":
             import re
+            from .mesh import make_mesh
             m_ = re.fullmatch(r"(\d+)x(\d+)", args.cim_mesh)
             if not m_:
                 ap.error(f"--cim-mesh must be 'auto', 'off' or 'DxM' "
                          f"(e.g. '1x8'), got {args.cim_mesh!r}")
             shape = (int(m_.group(1)), int(m_.group(2)))
-            if dist_on:
-                import numpy as np
-                from jax.sharding import Mesh
-                mesh = Mesh(np.array(jax.local_devices()).reshape(shape),
-                            ("data", "model"))
-            else:
-                mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"),
+                             devices=jax.local_devices())
         if mesh is not None:
             # the prefill/decode jits close over cfg — and so over the mesh
             cfg = cfg.replace(cim_mesh=mesh)
     key = jax.random.PRNGKey(0)
     sv = arch_serving(cfg)
     params = sv.init_params(key)
+    deploy_s = 0.0
     if args.cim:
         from ..core.types import CoreSpec
         from .mesh import serving_mesh_shape
@@ -259,6 +292,8 @@ def main(argv=None):
             params = verify_deployed(sv.deploy_cim(
                 jax.random.PRNGKey(7), params, mode=args.cim_mode,
                 mesh_shape=mesh_shape, spec=spec))
+            jax.block_until_ready(params)
+        deploy_s = sw.s
         tp = (dict(mesh.shape)["model"] if mesh is not None
               else mesh_shape.get("model", 1))
         n_packed = sum(1 for k in params["layers"] if k.endswith("_cim"))
@@ -275,7 +310,7 @@ def main(argv=None):
               f"tp={tp}, exec={exec_mode}) "
               f"in {sw.s:.1f}s")
     if args.traffic:
-        return _serve_traffic(args, cfg, params, mesh,
+        return _serve_traffic(args, cfg, params, mesh, deploy_s=deploy_s,
                               rank=rank, n_ranks=n_ranks)
 
     max_len = args.prompt_len + args.gen + (cfg.vis_patches or 0)
@@ -375,7 +410,8 @@ def main(argv=None):
     return out
 
 
-def _serve_traffic(args, cfg, params, mesh=None, rank=0, n_ranks=1):
+def _serve_traffic(args, cfg, params, mesh=None, deploy_s=0.0, rank=0,
+                   n_ranks=1):
     """Continuous-batching mode: open-loop Poisson traffic through the
     slotted pool (launch/scheduler.ContinuousBatchingEngine). On a real
     mesh the pool itself is placed per distributed/sharding.pool_pspecs
@@ -418,8 +454,10 @@ def _serve_traffic(args, cfg, params, mesh=None, rank=0, n_ranks=1):
     eng = ContinuousBatchingEngine(cfg, params, n_slots=slots,
                                    max_len=max_len, chunk=args.chunk,
                                    mesh=mesh, metrics=metrics, trace=trace,
-                                   strict_jit=args.strict_jit)
+                                   strict_jit=args.strict_jit,
+                                   capture_logits=args.capture_logits)
     stats = eng.run(reqs)
+    stats["deploy_s"] = deploy_s
     # per-rank, BEFORE any gather: a retracing replica must fail its own
     # process, not hide inside the fleet aggregate
     assert stats["decode_traces"] == 1, \
@@ -444,7 +482,7 @@ def _serve_traffic(args, cfg, params, mesh=None, rank=0, n_ranks=1):
                     "chunk": args.chunk, "rate": args.rate})
     if not dist_on:
         _write_obs(args, metrics, trace=trace, summary=summary)
-        return stats
+        return TrafficRun(stats, eng, reqs)
 
     # ---- rank-0 reporting contract: gather, merge, write once
     from ..obs import merge_registries
@@ -454,7 +492,7 @@ def _serve_traffic(args, cfg, params, mesh=None, rank=0, n_ranks=1):
         "summary": summary,
         "metrics": metrics.to_dict(extra_labels={"rank": str(rank)})})
     if rank != 0:
-        return stats
+        return TrafficRun(stats, eng, reqs)
     merged = merge_summaries([d["summary"] for d in docs])
     merged.update({"mode": "traffic", "arch": cfg.name,
                    "cim": bool(args.cim), "slots": slots,
@@ -469,7 +507,7 @@ def _serve_traffic(args, cfg, params, mesh=None, rank=0, n_ranks=1):
           f"decode_traces(max)={merged['decode_traces']}")
     _write_obs(args, merge_registries([d["metrics"] for d in docs]),
                trace=trace, summary=merged)
-    return stats
+    return TrafficRun(stats, eng, reqs)
 
 
 if __name__ == "__main__":

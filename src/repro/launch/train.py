@@ -6,13 +6,11 @@
 On TPU pods the same driver runs the full config on the production mesh; on
 this CPU container use --smoke (reduced config, 1 device). --cim noisy turns
 on NeuRRAM noise-resilient training for every linear layer (the paper's
-technique as a training-time feature). XLA latency-hiding flags for
-compute/collective overlap are appended on TPU backends.
+technique as a training-time feature).
 """
 from __future__ import annotations
 
 import argparse
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,12 +27,6 @@ from .steps import make_train_step, adamw_init_f32
 from .mesh import make_production_mesh, data_axes
 
 
-def _tpu_overlap_flags():
-    return (" --xla_tpu_enable_latency_hiding_scheduler=true"
-            " --xla_tpu_enable_async_collective_fusion=true"
-            " --xla_tpu_overlap_compute_collective_tc=true")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b")
@@ -48,10 +40,8 @@ def main(argv=None):
     ap.add_argument("--cim", default="off", choices=["off", "noisy"])
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args(argv)
-
-    if jax.default_backend() == "tpu":
-        os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") \
-            + _tpu_overlap_flags()
+    from .env import enable_compile_cache
+    enable_compile_cache()
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     cfg = cfg.replace(cim_mode=args.cim,

@@ -63,7 +63,6 @@ def _expert_matmul(p: Dict, name: str, xe, cfg, *, seed: int = 0):
     if pcl is None or getattr(cfg, "cim_mode", "off") != "packed":
         return jnp.einsum("ecd,edf->ecf", xe, p[name])
     from . import nn as nn_mod
-    from jax.experimental.shard_map import shard_map
     ccfg = nn_mod.arch_cim_config(cfg)
     mesh = getattr(cfg, "cim_mesh", None)
     m = dict(mesh.shape).get("model", 1) if mesh is not None else 1
@@ -79,9 +78,9 @@ def _expert_matmul(p: Dict, name: str, xe, cfg, *, seed: int = 0):
                                                seed=seed + base + el))
             return jnp.stack(ys)
 
-        fn = shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P("model"), P("model")),
-                       out_specs=P("model"), check_rep=False)
+        fn = jax.shard_map(shard_fn, mesh=mesh,
+                           in_specs=(P("model"), P("model")),
+                           out_specs=P("model"), check_vma=False)
         return fn(pcl, xe).astype(xe.dtype)
     ys = []
     for e in range(cfg.n_experts):
@@ -166,7 +165,6 @@ def moe_ffn_ep_shardmap(p: Dict, x, cfg, mesh, capacity_factor: float = 1.25,
     dispatch instead (transformer.dense_block forces this), since only that
     path drives the per-expert compiled chips.
     """
-    from jax.experimental.shard_map import shard_map
     axes = [a for a in data_axes if a in mesh.axis_names]
     ep = mesh.shape[model_axis]
     e_local = cfg.n_experts // ep
@@ -238,11 +236,11 @@ def moe_ffn_ep_shardmap(p: Dict, x, cfg, mesh, capacity_factor: float = 1.25,
 
     seq_ok = x.shape[1] % ep == 0
     xspec = P(tuple(axes), model_axis if seq_ok else None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(model_axis), P(model_axis), P(model_axis), xspec),
         out_specs=xspec,
-        check_rep=False)
+        check_vma=False)
     y = fn(p["router"], p["ew_g"], p["ew_i"], p["ew_o"], x)
     if cfg.n_shared_experts > 0:
         b, s, d = x.shape

@@ -21,8 +21,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.types import CIMConfig, CoreSpec, NonIdealityConfig
 from ..core.quant import pact_quantize
@@ -201,7 +200,7 @@ class ShardedPackedLayer:
     serve it: `sharded_packed_forward` runs each shard device-resident
     under shard_map on a real mesh (deploy-time placement maps the shard
     dim onto 'model'); `sharded_packed_loop` unrolls the shards in one
-    process — the single-device fallback and the parity oracle."""
+    process — the no-mesh executor and the parity oracle."""
     shards: Any            # PackedCIMLayer, leading (n_shards,) on arrays
     partition: str         # 'col' | 'row' | 'none'
     n_shards: int
@@ -216,8 +215,9 @@ class ShardedPackedLayer:
 
 def sharded_packed_loop(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
                         seed: int = 0):
-    """Unrolled-loop executor for a ShardedPackedLayer — the SINGLE-DEVICE
-    FALLBACK and the PARITY ORACLE for the shard_map path.
+    """Unrolled-loop executor for a ShardedPackedLayer — the NO-MESH
+    executor (`serve --cim-mesh off`) and the PARITY ORACLE for the
+    shard_map path.
 
     x: (B, R_global) float. Every shard's packed Pallas dispatch runs in
     one process, unrolled inside the serving jit (identical per-shard plan
@@ -298,16 +298,22 @@ def sharded_packed_forward(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
         when 1-ulp nondeterminism vs the single-device oracle is
         acceptable.
 
-    Without a mesh — or when the mesh's 'model' width does not match the
-    deploy (e.g. a chip stack deployed wider than the local device count)
-    — execution falls back to `sharded_packed_loop`, the documented
-    single-device executor and the parity oracle the shard_map path is
-    bitwise-tested against. Replicated projections (n_shards == 1) always
-    take the loop (one dispatch, replicated over the mesh by GSPMD).
+    Without a mesh (`serve --cim-mesh off`, the parity oracle) execution
+    takes `sharded_packed_loop`, the single-device executor the shard_map
+    path is bitwise-tested against. Replicated projections (n_shards == 1)
+    always take it too (one dispatch, replicated over the mesh by GSPMD).
+    A multi-shard stack meeting a mesh whose 'model' width differs from its
+    shard count raises: it was deployed for another mesh, and looping over
+    its shards on one device would hide that.
     """
-    if mesh is None or spl.n_shards == 1 \
-            or dict(mesh.shape).get("model", 1) != spl.n_shards:
+    if mesh is None or spl.n_shards == 1:
         return sharded_packed_loop(spl, x, ccfg, seed=seed)
+    width = dict(mesh.shape).get("model", 1)
+    if width != spl.n_shards:
+        raise ValueError(
+            f"projection deployed over {spl.n_shards} 'model' shards meets "
+            f"a mesh whose 'model' axis has {width} devices: deploy for "
+            "this mesh, or serve without one (--cim-mesh off)")
     part = spl.partition
 
     def shard_fn(shards, xs):
@@ -324,9 +330,9 @@ def sharded_packed_forward(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
 
     x_spec = P(None, "model") if part == "row" else P()
     out_spec = P(None, "model") if part == "col" else P()
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P("model"), x_spec), out_specs=out_spec,
-                   check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P("model"), x_spec), out_specs=out_spec,
+                       check_vma=False)
     return fn(spl.shards, x)
 
 
@@ -371,9 +377,17 @@ def deploy_packed_stack(key, stacked_w: Dict[str, jax.Array],
             {n: stacked_w[n][li].astype(jnp.float32) for n in names},
             ccfg, spec, mode, in_alpha=in_alpha)
         per_layer.append(chip.layers)
-    return {n: jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *[pl[n] for pl in per_layer])
-        for n in names}
+    return {n: _stack([pl.pop(n) for pl in per_layer]) for n in names}
+
+
+def _stack(trees, axis: int = 0):
+    """Stack same-structure pytrees leaf by leaf. Callers pop the inputs
+    out of their containers first, so each projection's unstacked arrays
+    are freed as soon as its stack exists: at published widths a layer's
+    chip state is gigabytes, and holding every projection twice at once
+    would not fit one accelerator."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs, axis=axis),
+                                  *trees)
 
 
 def packed_linear(pcl, x, ccfg: CIMConfig, *, seed: int = 0, mesh=None):
@@ -444,6 +458,22 @@ def place_packed_stack(tree, mesh, n_shards: int, shard_axis: int = 0):
     return placed
 
 
+def place_replicated(tree, mesh):
+    """Replicate over `mesh` every array leaf not already placed on it —
+    the float parameters a deploy leaves behind (embeddings, norms,
+    attention biases). Done once at deploy time: a leaf left on one device
+    would be copied to every mesh device on each serving call."""
+    rep = NamedSharding(mesh, P())
+
+    def put(a):
+        sh = getattr(a, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.mesh == mesh \
+                or sh is not None and sh.is_equivalent_to(rep, a.ndim):
+            return a                    # placed already: never a copy
+        return jax.device_put(a, rep)
+    return jax.tree_util.tree_map(put, tree)
+
+
 def _deploy_sharded_stacks(key, stacked: Dict[str, jax.Array],
                            ccfg: CIMConfig, *, mode: str,
                            in_alpha: Union[float, Dict[str, float]],
@@ -510,12 +540,12 @@ def _deploy_sharded_stacks(key, stacked: Dict[str, jax.Array],
     for n in stacked:
         if kinds[n] == "none":
             pcl = jax.tree_util.tree_map(lambda a: a[:, None],
-                                         none_layers[n])
+                                         none_layers.pop(n))
             out[n] = ShardedPackedLayer(pcl, "none", 1)
         else:
-            spl = ShardedPackedLayer(jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs, axis=1),
-                *[sl[n] for sl in shard_layers]), kinds[n], n_sh)
+            spl = ShardedPackedLayer(
+                _stack([sl.pop(n) for sl in shard_layers], axis=1),
+                kinds[n], n_sh)
             if mesh is not None:
                 spl = place_packed_stack(spl, mesh, n_sh, shard_axis=1)
             out[n] = spl
@@ -615,9 +645,7 @@ def deploy_transformer_cim(key, params, arch_cfg, *, mode: str = "ideal",
             for e in range(n_experts)]
         n_model = int(mesh_shape.get("model", 1))
         for n in names:
-            stack = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs, axis=1),
-                *[pe[n] for pe in per_exp])
+            stack = _stack([pe.pop(n) for pe in per_exp], axis=1)
             if mesh is not None and n_model > 1 \
                     and n_experts % n_model == 0:
                 # expert-parallel placement: the E dim is the shard axis
@@ -627,6 +655,8 @@ def deploy_transformer_cim(key, params, arch_cfg, *, mode: str = "ideal",
 
     out = dict(params)
     out["layers"] = new_layers
+    if mesh is not None:
+        out = place_replicated(out, mesh)
     # compile_chip verified each per-layer chip; this pass re-checks the
     # STACKED artifacts (trailing-dim shapes + shared static geometry)
     # after the tree_map(stack) / device placement surgery above
@@ -734,6 +764,8 @@ def deploy_recurrent_cim(key, params, arch_cfg, *, mode: str = "ideal",
                                          shard_axis=0)
             new_sa[n + "_cim"] = spl
         out["shared_attn"] = new_sa
+    if mesh is not None:
+        out = place_replicated(out, mesh)
     # re-verify the stacked artifacts post-stack/strip/placement (the
     # per-chip compiles were already strict-verified)
     return verify_deployed(out)

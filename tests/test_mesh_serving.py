@@ -88,21 +88,25 @@ def _dense_deploy(n_shards, **cfg_kw):
 
 
 def test_mesh_width_mismatch_falls_back_to_loop():
-    """A chip stack deployed wider than the mesh's 'model' axis serves
-    through the unrolled loop — bitwise the same as serving without a
-    mesh (the documented fallback contract)."""
+    """A chip stack deployed wider than the mesh's 'model' axis raises,
+    naming both widths: a stack deployed for another mesh must not serve
+    silently from one device. Without a mesh the same stack serves through
+    the loop (the executor `--cim-mesh off` asks for)."""
     import repro.models.nn as nn
+    from repro.launch.mesh import make_mesh
     cfg, params, p = _dense_deploy(2)
     spl = p["layers"]["wq_cim"]
     spl0 = nn.ShardedPackedLayer(
         jax.tree_util.tree_map(lambda a: a[0], spl.shards),
         spl.partition, spl.n_shards)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))   # model=1 != 2 shards
+    mesh = make_mesh((1, 1), ("data", "model"))   # model=1 != 2 shards
     ccfg = nn.arch_cim_config(cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, cfg.d_model))
     y_none = nn.sharded_packed_forward(spl0, x, ccfg)
-    y_mesh = nn.sharded_packed_forward(spl0, x, ccfg, mesh=mesh)
-    np.testing.assert_array_equal(np.asarray(y_none), np.asarray(y_mesh))
+    np.testing.assert_array_equal(
+        np.asarray(y_none), np.asarray(nn.sharded_packed_loop(spl0, x, ccfg)))
+    with pytest.raises(ValueError, match="2 'model' shards.*1 devices"):
+        nn.sharded_packed_forward(spl0, x, ccfg, mesh=mesh)
 
 
 def test_mesh_and_mesh_shape_width_disagreement_raises():
