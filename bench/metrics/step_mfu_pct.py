@@ -1,0 +1,16 @@
+"""step_mfu_pct: model FLOPs of the work the window completed (bench/flops:
+2 x matmul parameters per useful row, the LM head where its logits are
+used, attention over each row's KV length or the RWKV-6 recurrence), over
+the traced window times the chip's peak FLOP/s (bench/peaks.json)."""
+from bench import flops
+
+
+def read(ctx):
+    if ctx.trace is None or not ctx.trace.get("window") or not ctx.peaks:
+        return None
+    w0, w1 = ctx.trace["window"]
+    work = sum(flops.request_flops(ctx.config, len(r.prompt), len(r.tokens))
+               for r in ctx.requests if r.tokens)
+    if not work:
+        return None
+    return 100.0 * work / ((w1 - w0) * 1e-9 * ctx.peaks["flops_per_s"])
