@@ -100,9 +100,6 @@ def run(arch="gemma2-9b", *, quick=False, cim=False, n_requests=None,
     stat = serve_static(eng.cfg, params, _requests(tr, n), batch=slots,
                         max_len=max_len, metrics=metrics)
 
-    # registry-derived quantiles (log-bucket interpolated) ride along so
-    # the bench rows can be cross-checked against a --metrics-out dump
-    h_tok = metrics.get("serve_token_lat_s")
     rows = [
         (f"continuous_{arch}", cont["p50_ms"] * 1e3, {
             "p50_ms": cont["p50_ms"], "p99_ms": cont["p99_ms"],
@@ -113,7 +110,6 @@ def run(arch="gemma2-9b", *, quick=False, cim=False, n_requests=None,
             "decode_traces": cont["decode_traces"],
             "jit_traces_pool_decode": metrics.value(
                 "jit_traces", entry="pool_decode"),
-            "registry_p50_ms": h_tok.quantile(0.5) * 1e3,
             "registry_tokens": int(
                 metrics.value("serve_tokens_generated")),
             "mvm_dispatches": cont["mvm_dispatches"],

@@ -61,7 +61,7 @@ from ..obs.chipmeter import ChipMeter
 from ..obs.clock import now as clock_now
 from ..obs.clock import timed_call
 from ..obs.jitwatch import JitWatcher
-from ..obs.trace import ENGINE_PID, REQUEST_PID
+from ..obs.trace import ENGINE_PID, REQUEST_PID, Tracer
 from .steps import (POOL_KEYS, arch_serving, make_pool_decode_step,
                     make_slot_prefill_step)
 
@@ -170,12 +170,21 @@ class ContinuousBatchingEngine:
             from ..distributed.sharding import pool_pspecs
             ns = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), pool_pspecs(self.pool))
+        # Telemetry is always collected (one code path — metrics can't
+        # perturb what they measure) into a private registry unless the
+        # caller supplies a shared one; the trace buffer is opt-in. Phase
+        # spans (obs/trace.Tracer) are always recorded: the profiler keeps
+        # them only while it traces.
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self.trace = trace
+        self.tracer = Tracer(self.metrics, trace)
         # Every engine jit goes through the watchdog: trace counts become a
-        # metric on every run and, under strict_jit, a hard assertion. The
-        # wrapper forwards calls verbatim (same donation/shardings/static
-        # args), so compiled semantics — and the bitwise pool-vs-static
-        # contract — are untouched whether metrics are read or not.
-        self.jitwatch = JitWatcher(strict=strict_jit)
+        # metric on every run and, under strict_jit, a hard assertion; each
+        # call is a serve.dispatch.<entry> span. The wrapper forwards calls
+        # verbatim (same donation/shardings/static args), so compiled
+        # semantics — and the bitwise pool-vs-static contract — are
+        # untouched whether metrics are read or not.
+        self.jitwatch = JitWatcher(strict=strict_jit, tracer=self.tracer)
         self._decode = self.jitwatch.wrap(
             "pool_decode", make_pool_decode_step(cfg), max_traces=1,
             donate_argnums=(1,),
@@ -196,11 +205,6 @@ class ContinuousBatchingEngine:
         self._jobs: deque = deque()            # chunked prefills in flight
         self._rows_useful = 0                  # token rows that reached a req
         self._rows_dispatched = 0              # rows pushed through the chips
-        # Telemetry is always collected (one code path — metrics can't
-        # perturb what they measure) into a private registry unless the
-        # caller supplies a shared one; the trace buffer is opt-in.
-        self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.trace = trace
         self.chipmeter = ChipMeter.from_params(
             params, cfg.cim_in_bits, cfg.cim_out_bits)
         m = self.metrics
@@ -228,8 +232,6 @@ class ContinuousBatchingEngine:
             "serve_ttft_s", "arrival to first token, seconds")
         self._h_req = m.histogram(
             "serve_request_s", "arrival to last token, seconds")
-        self._h_tok = m.histogram(
-            "serve_token_lat_s", "per-token step latency, seconds")
 
     # ------------------------------------------------------------- plumbing
 
@@ -269,11 +271,13 @@ class ContinuousBatchingEngine:
     def _admit(self, req: Request) -> None:
         assert len(req.prompt) + req.max_new <= self.max_len, \
             f"request {req.rid} would overflow the slot (max_len)"
-        slot = self._free.pop(0)
-        assert slot not in self._live, "slot double-assign"
-        self.pool = self._reset(self.pool, jnp.int32(slot))
-        self._jobs.append(_PrefillJob(slot, req, self._chunks(req.prompt)))
-        self._m_admitted.inc()
+        with self.tracer.span("serve.admit", rid=req.rid):
+            slot = self._free.pop(0)
+            assert slot not in self._live, "slot double-assign"
+            self.pool = self._reset(self.pool, jnp.int32(slot))
+            self._jobs.append(_PrefillJob(slot, req,
+                                          self._chunks(req.prompt)))
+            self._m_admitted.inc()
 
     def _request_done(self, req: Request, slot: int) -> None:
         """Telemetry at a request's last token: latency histograms, its
@@ -299,11 +303,24 @@ class ContinuousBatchingEngine:
 
     def _finish(self, slot: int, now: float) -> None:
         req = self._live.pop(slot)
-        req.t_done = now
-        self.pool = self._activate(self.pool, jnp.int32(slot), False)
-        self._free.append(slot)
-        self._free.sort()
-        self._request_done(req, slot)
+        with self.tracer.span("serve.finish", rid=req.rid):
+            req.t_done = now
+            self.pool = self._activate(self.pool, jnp.int32(slot), False)
+            self._free.append(slot)
+            self._free.sort()
+            self._request_done(req, slot)
+
+    def _dispatch_and_wait(self, fn, wait: str, *args):
+        """(outputs, seconds) of one step: the watched jit's dispatch (its
+        serve.dispatch.<entry> span), then block_until_ready under the
+        `wait` span. The seconds run to the wait's end and cover both, as
+        the step histograms (serve_decode_step_s, serve_prefill_chunk_s)
+        have always timed."""
+        t0 = clock_now()
+        out = fn(*args)
+        with self.tracer.span(wait) as waited:
+            jax.block_until_ready(out)
+        return out, waited.t1 - t0
 
     def _prefill_one_chunk(self, now: float) -> float:
         """Run ONE chunk of the oldest in-flight prefill; returns step
@@ -311,82 +328,109 @@ class ContinuousBatchingEngine:
         seeded into pool['tok'] by the chunk step)."""
         job = self._jobs[0]
         chunk = job.chunks[job.next]
-        toks = jnp.asarray(chunk[None], jnp.int32)
-        (logits, self.pool), dt = timed_call(
-            self._prefill, self.params, self.pool, toks, jnp.int32(job.slot))
-        job.next += 1
-        n_rows = len(chunk)
-        self._m_chunks.inc()
-        self._m_tok_pre.inc(n_rows)
-        self._h_chunk.observe(dt)
-        self.chipmeter.count_rows(n_rows)
-        self._rows_useful += n_rows
-        self._rows_dispatched += n_rows
-        if self.trace is not None:
-            args = {"slot": job.slot, "rid": job.req.rid, "rows": n_rows,
-                    "chunk": job.next, "of": len(job.chunks)}
-            self.trace.complete("prefill_chunk", now, dt, args=args)
-            self.trace.complete("prefill_chunk", now, dt, pid=REQUEST_PID,
-                                tid=job.req.rid, args=args)
-        if job.next == len(job.chunks):
-            self._jobs.popleft()
-            req = job.req
-            first = int(np.argmax(np.asarray(logits[0])))
-            req.tokens.append(first)
-            req.token_lat.append(dt)
-            self._m_tok_gen.inc()
-            self._h_tok.observe(dt)
-            req.t_first = now + dt - req.arrival
-            self._h_ttft.observe(req.t_first)
-            if self.capture_logits:
-                req.logits.append(np.asarray(logits[0]))
-            if req.max_new == 1:
-                req.t_done = now + dt
-                self.pool = self._reset(self.pool, jnp.int32(job.slot))
-                self._free.append(job.slot)
-                self._free.sort()
-                self._request_done(req, job.slot)
-            else:
-                self.pool = self._activate(self.pool, jnp.int32(job.slot),
-                                           True)
-                self._live[job.slot] = req
-        return dt
+        with self.tracer.span("serve.prefill", rid=job.req.rid,
+                              slot=job.slot, chunk=job.next + 1,
+                              of=len(job.chunks)):
+            toks = jnp.asarray(chunk[None], jnp.int32)
+            (logits, self.pool), dt = self._dispatch_and_wait(
+                self._prefill, "serve.prefill.wait", self.params, self.pool,
+                toks, jnp.int32(job.slot))
+            job.next += 1
+            n_rows = len(chunk)
+            self._m_chunks.inc()
+            self._m_tok_pre.inc(n_rows)
+            self._h_chunk.observe(dt)
+            self.chipmeter.count_rows(n_rows)
+            self._rows_useful += n_rows
+            self._rows_dispatched += n_rows
+            if self.trace is not None:
+                self.trace.complete("prefill_chunk", now, dt, pid=REQUEST_PID,
+                                    tid=job.req.rid,
+                                    args={"slot": job.slot, "rid": job.req.rid,
+                                          "rows": n_rows, "chunk": job.next,
+                                          "of": len(job.chunks)})
+            if job.next == len(job.chunks):
+                self._jobs.popleft()
+                req = job.req
+                with self.tracer.span("serve.prefill.readback"):
+                    first = int(np.argmax(np.asarray(logits[0])))
+                    if self.capture_logits:
+                        req.logits.append(np.asarray(logits[0]))
+                req.tokens.append(first)
+                req.token_lat.append(dt)
+                self._m_tok_gen.inc()
+                req.t_first = now + dt - req.arrival
+                self._h_ttft.observe(req.t_first)
+                if req.max_new == 1:
+                    with self.tracer.span("serve.finish", rid=req.rid):
+                        req.t_done = now + dt
+                        self.pool = self._reset(self.pool, jnp.int32(job.slot))
+                        self._free.append(job.slot)
+                        self._free.sort()
+                        self._request_done(req, job.slot)
+                else:
+                    self.pool = self._activate(self.pool, jnp.int32(job.slot),
+                                               True)
+                    self._live[job.slot] = req
+            return dt
 
     def _decode_once(self, now: float) -> float:
-        (logits, self.pool), dt = timed_call(self._decode, self.params,
-                                             self.pool)
         # Honest hardware accounting: the weight-stationary pool step
         # pushes ALL n_slots rows through every chip regardless of
         # occupancy — empty slots still cost energy. The useful/dispatched
         # ratio surfaces as the run's `utilization`.
         n_live = len(self._live)
-        self._m_steps.inc()
-        self._m_tok_gen.inc(n_live)
-        self._h_decode.observe(dt)
-        self.chipmeter.count_rows(self.n_slots)
-        self._rows_useful += n_live
-        self._rows_dispatched += self.n_slots
-        if self.trace is not None:
-            self.trace.complete("decode_step", now, dt,
-                                args={"live": n_live})
-        toks = np.asarray(self.pool["tok"][:, 0])
-        done = []
-        for slot, req in self._live.items():
-            req.tokens.append(int(toks[slot]))
-            req.token_lat.append(dt)
-            self._h_tok.observe(dt)
-            if self.capture_logits:
-                req.logits.append(np.asarray(logits[slot]))
-            if self.trace is not None:
-                self.trace.complete("decode", now, dt, pid=REQUEST_PID,
-                                    tid=req.rid, args={"slot": slot})
-            if len(req.tokens) >= req.max_new:
-                done.append(slot)
-        for slot in done:
-            self._finish(slot, now + dt)
+        with self.tracer.span("serve.decode", live=n_live):
+            (logits, self.pool), dt = self._dispatch_and_wait(
+                self._decode, "serve.decode.wait", self.params, self.pool)
+            self._m_steps.inc()
+            self._m_tok_gen.inc(n_live)
+            self._h_decode.observe(dt)
+            self.chipmeter.count_rows(self.n_slots)
+            self._rows_useful += n_live
+            self._rows_dispatched += self.n_slots
+            with self.tracer.span("serve.decode.readback"):
+                toks = np.asarray(self.pool["tok"][:, 0])
+            done = []
+            with self.tracer.span("serve.decode.emit"):
+                for slot, req in self._live.items():
+                    req.tokens.append(int(toks[slot]))
+                    req.token_lat.append(dt)
+                    if self.capture_logits:
+                        req.logits.append(np.asarray(logits[slot]))
+                    if self.trace is not None:
+                        self.trace.complete("decode", now, dt,
+                                            pid=REQUEST_PID, tid=req.rid,
+                                            args={"slot": slot})
+                    if len(req.tokens) >= req.max_new:
+                        done.append(slot)
+            for slot in done:
+                self._finish(slot, now + dt)
         return dt
 
     # -------------------------------------------------------------- serving
+
+    def _schedule(self, pending: deque, t0: float, realtime: bool,
+                  occ_last: tuple) -> tuple:
+        """Top of a loop iteration: admit arrived requests to free slots,
+        set the occupancy gauges, and write the occupancy counter when it
+        changed. Returns the occupancy written last."""
+        now = clock_now() - t0
+        while pending and self._free and \
+                (not realtime or pending[0].arrival <= now):
+            pending[0].t_admit = now
+            self._admit(pending.popleft())
+        arrived = sum(r.arrival <= now for r in pending) \
+            if realtime else len(pending)
+        self._g_occ.set(len(self._live))
+        self._g_queue.set(arrived + len(self._jobs))
+        occ = (len(self._live), len(self._jobs), arrived)
+        if self.trace is not None and occ != occ_last:
+            self.trace.counter("occupancy", now, {
+                "live_slots": occ[0], "prefilling": occ[1],
+                "queued": occ[2]})
+            return occ
+        return occ_last
 
     def run(self, requests: List[Request], *, warm: bool = True,
             realtime: bool = True) -> Dict[str, Any]:
@@ -406,43 +450,37 @@ class ContinuousBatchingEngine:
             self.trace.name_process(REQUEST_PID, "requests")
         pending = deque(sorted(requests, key=lambda r: (r.arrival, r.rid)))
         t0 = clock_now()
-        step_lat: List[float] = []
+        self.tracer.origin = t0
+        span = self.tracer.span
         occ_last = (-1, -1, -1)
-        while pending or self._jobs or self._live:
-            now = clock_now() - t0
-            while pending and self._free and \
-                    (not realtime or pending[0].arrival <= now):
-                pending[0].t_admit = now
-                self._admit(pending.popleft())
-            arrived = sum(r.arrival <= now for r in pending) \
-                if realtime else len(pending)
-            self._g_occ.set(len(self._live))
-            self._g_queue.set(arrived + len(self._jobs))
-            occ = (len(self._live), len(self._jobs), arrived)
-            if self.trace is not None and occ != occ_last:
-                occ_last = occ
-                self.trace.counter("occupancy", now, {
-                    "live_slots": occ[0], "prefilling": occ[1],
-                    "queued": occ[2]})
-            busy = False
-            # each step re-reads the clock: prefill and decode run
-            # sequentially within an iteration, and span starts must
-            # reflect the wall time the step actually began — stamping
-            # both with the top-of-loop `now` would overlap their spans
-            # (and let a slow prefill's span spill past a request that
-            # finished in the decode right after it)
-            if self._jobs:
-                self._prefill_one_chunk(clock_now() - t0)
-                busy = True
-            if self._live:
-                step_lat.append(self._decode_once(clock_now() - t0))
-                busy = True
-            if not busy:
-                # idle: nothing in flight, next request not yet arrived
-                if pending and realtime:
-                    wait = pending[0].arrival - (clock_now() - t0)
-                    if wait > 0:
-                        time.sleep(min(wait, 0.05))
+        with self.tracer.gc_spans():
+            while pending or self._jobs or self._live:
+                with span("serve.iter"):
+                    with span("serve.schedule"):
+                        occ_last = self._schedule(pending, t0, realtime,
+                                                  occ_last)
+                    busy = False
+                    # each step re-reads the clock: prefill and decode run
+                    # sequentially within an iteration, and span starts
+                    # must reflect the wall time the step actually began —
+                    # stamping both with the top-of-loop `now` would
+                    # overlap their spans (and let a slow prefill's span
+                    # spill past a request that finished in the decode
+                    # right after it)
+                    if self._jobs:
+                        self._prefill_one_chunk(clock_now() - t0)
+                        busy = True
+                    if self._live:
+                        self._decode_once(clock_now() - t0)
+                        busy = True
+                    if not busy and pending and realtime:
+                        # idle: nothing in flight, next request not yet
+                        # arrived
+                        wait = pending[0].arrival - (clock_now() - t0)
+                        if wait > 0:
+                            with span("serve.sleep"):
+                                time.sleep(min(wait, 0.05))
+        self.tracer.origin = None
         wall = clock_now() - t0
         self._g_occ.set(0)
         self._g_queue.set(0)

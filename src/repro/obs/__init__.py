@@ -4,9 +4,11 @@ measured efficiency claims, wired through the whole serving stack.
 The paper's headline numbers are MEASUREMENTS — per-MVM energy, TOPS/W,
 EDP vs prior art (Fig. 4, Ext. Data Fig. 10) — but until this package the
 serving stack could only reproduce them offline through bench scripts.
-Four pieces, all host-side and outside every jit (zero hot-path overhead:
-collection happens only at report boundaries where the engine already
-blocks on `block_until_ready`):
+Four pieces, all host-side and outside every jit: recording adds no
+device sync and no traced value, so tokens are bitwise the same with
+every output on or off. Counters and histograms are updated at step
+boundaries; the engine loop's phase spans also open and close around each
+dispatch and wait, a few microseconds each, always on:
 
   * `metrics`   — process-local registry of counters / gauges /
                   log-bucketed histograms with JSON + Prometheus export.
@@ -17,9 +19,14 @@ blocks on `block_until_ready`):
                   serving-time realization of the paper's Fig. 4 energy
                   accounting (same model as bench_mapping's
                   `precision_serve_b*` rows).
-  * `trace`     — per-request span timelines (admit -> prefill chunks ->
-                  decode steps -> finish) as Chrome trace-event JSON,
-                  loadable in Perfetto / chrome://tracing.
+  * `trace`     — `Tracer`, the one span API: engine-loop phase spans
+                  (`serve.iter` and the phases nested in it) as JAX
+                  profiler annotations on the profiler's clock, as the
+                  `serve_phase_s{phase=...}` histogram, and, with a
+                  `TraceBuffer` attached, as Chrome trace-event JSON
+                  together with the per-request timelines (admit ->
+                  prefill chunks -> decode steps -> finish), loadable in
+                  Perfetto / chrome://tracing.
   * `jitwatch`  — jit wrappers that count traces and compile time per
                   entry point, turning the one-trace-per-plan /
                   pinned-out_shardings contract (PR 7's GSPMDSharding
@@ -36,4 +43,4 @@ from .chipmeter import ChipMeter  # noqa: F401
 from .jitwatch import JitRetraceError, JitWatcher  # noqa: F401
 from .metrics import (MetricsRegistry, dict_to_prometheus,  # noqa: F401
                       merge_registries)
-from .trace import TraceBuffer  # noqa: F401
+from .trace import TraceBuffer, Tracer  # noqa: F401

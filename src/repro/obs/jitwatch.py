@@ -19,7 +19,9 @@ an already-dispatched call — no device sync, no traced values. Compile
 time is attributed by wall clock: a call that grew the cache carries its
 (compile + dispatch) seconds into `compile_s`, which is exactly how the
 engine's warmup accounting wants it (warmup absorbs the compile; steady
-state must never grow the cache again).
+state must never grow the cache again). With a `Tracer`, every call is a
+`serve.dispatch.<entry>` span: the enqueue of that jit, in one place for
+every engine entry point.
 """
 from __future__ import annotations
 
@@ -54,11 +56,19 @@ class WatchedJit:
         self.calls = 0
         self.compile_s = 0.0
         self._watcher = watcher
+        self.span_name = f"serve.dispatch.{name}"
         functools.update_wrapper(self, fun,
                                  assigned=("__doc__", "__name__"),
                                  updated=())
 
     def __call__(self, *args, **kwargs):
+        tracer = self._watcher.tracer
+        if tracer is None:
+            return self._call(args, kwargs)
+        with tracer.span(self.span_name):
+            return self._call(args, kwargs)
+
+    def _call(self, args, kwargs):
         t0 = clock.now()
         out = self.jitted(*args, **kwargs)
         self.calls += 1
@@ -93,11 +103,12 @@ class JitWatcher:
     `max_traces` budget raises at the offending call. `seal()` (either
     mode) freezes the trace set — ANY later trace on any entry raises;
     the engine seals after warmup so steady-state serving is guaranteed
-    compile-free.
+    compile-free. `tracer` (obs/trace.Tracer) makes each call a span.
     """
 
-    def __init__(self, *, strict: bool = False):
+    def __init__(self, *, strict: bool = False, tracer=None):
         self.strict = strict
+        self.tracer = tracer              # obs/trace.Tracer, or None
         self.sealed = False
         self.entries: Dict[str, WatchedJit] = {}
 

@@ -2,11 +2,11 @@
 histograms, with JSON and Prometheus-text export.
 
 Design constraints (the serving stack's hard rule — see obs/__init__):
-everything here is plain host-side Python updated at step boundaries
-where the engine already blocked on the device, so recording can never
-add a device sync or a traced value. Costs are a few dict operations per
-observation against millisecond-scale serving steps. Single-threaded by
-design (the engine loop is single-threaded); no locks.
+everything here is plain host-side Python updated between device calls,
+so recording can never add a device sync or a traced value. Costs are a
+few dict operations per observation against millisecond-scale serving
+steps. Single-threaded by design (the engine loop is single-threaded); no
+locks.
 
 Histograms are log-bucketed: geometric bucket boundaries cover the whole
 latency range (default 1 us .. ~137 s at x2 per bucket) in ~27 buckets,
@@ -108,14 +108,26 @@ class Gauge:
 
 
 class _HistogramSeries:
-    __slots__ = ("counts", "count", "sum", "min", "max")
+    """One labeled series of a Histogram (`Histogram.bind` hands it out,
+    so a hot caller resolves its labels once)."""
+    __slots__ = ("bounds", "counts", "count", "sum", "min", "max")
 
-    def __init__(self, n_buckets: int):
-        self.counts = [0] * (n_buckets + 1)     # +1 = overflow (+Inf)
+    def __init__(self, bounds: List[float]):
+        self.bounds = bounds
+        self.counts = [0] * (len(bounds) + 1)   # +1 = overflow (+Inf)
         self.count = 0
         self.sum = 0.0
         self.min = math.inf
         self.max = -math.inf
+
+    def observe(self, value: float) -> None:
+        self.counts[bisect.bisect_left(self.bounds, value)] += 1
+        self.count += 1
+        self.sum += value
+        if value < self.min:
+            self.min = value
+        if value > self.max:
+            self.max = value
 
 
 class Histogram:
@@ -139,16 +151,16 @@ class Histogram:
         key = _label_key(labels)
         s = self._series.get(key)
         if s is None:
-            s = self._series[key] = _HistogramSeries(len(self.bounds))
+            s = self._series[key] = _HistogramSeries(self.bounds)
         return s
 
     def observe(self, value: float, **labels) -> None:
-        s = self._get(labels)
-        s.counts[bisect.bisect_left(self.bounds, value)] += 1
-        s.count += 1
-        s.sum += value
-        s.min = min(s.min, value)
-        s.max = max(s.max, value)
+        self._get(labels).observe(value)
+
+    def bind(self, **labels) -> _HistogramSeries:
+        """The series of `labels`, created if new; its `observe(value)`
+        skips the label lookup."""
+        return self._get(labels)
 
     def count(self, **labels) -> int:
         key = _label_key(labels)

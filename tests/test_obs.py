@@ -3,7 +3,11 @@ energy reconciliation against core/energy.mvm_cost, Chrome-trace span
 timelines, the jit-cache watchdog, and the zero-perturbation contract —
 serving with metrics + tracing on emits BITWISE the same tokens as
 serving with them off."""
+import gc
+import glob
 import json
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +22,10 @@ from repro.obs import MetricsRegistry, TraceBuffer
 from repro.obs.chipmeter import ChipMeter
 from repro.obs.jitwatch import JitRetraceError, JitWatcher
 from repro.obs.trace import ENGINE_PID, REQUEST_PID
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import check_obs  # noqa: E402
+from jax.profiler import ProfileData  # noqa: E402
 
 
 def _cfg(arch="gemma2-9b", cim=False):
@@ -269,10 +277,11 @@ def test_engine_stats_reconcile_with_meters():
     assert h.count() == len(reqs)
 
 
-def test_metrics_do_not_perturb_tokens():
+def test_metrics_do_not_perturb_tokens(tmp_path):
     """The zero-overhead contract, stated as bitwise determinism: a run
-    with a shared registry + trace buffer + strict watchdog emits EXACTLY
-    the token ids of a bare run over the same request stream."""
+    with a shared registry + trace buffer + strict watchdog, its phase
+    spans recorded by a running JAX profiler, emits EXACTLY the token ids
+    of a bare run over the same request stream."""
     cfg = _cfg()
     params = _params(cfg)
     lens, gens = [32, 64, 32, 32], [4, 2, 3, 5]
@@ -285,10 +294,126 @@ def test_metrics_do_not_perturb_tokens():
     eng1 = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=96,
                                     metrics=MetricsRegistry(),
                                     trace=TraceBuffer(), strict_jit=True)
-    eng1.run(metered, realtime=False)
+    with jax.profiler.trace(str(tmp_path)):
+        eng1.run(metered, realtime=False)
 
     for r0, r1 in zip(bare, metered):
         assert r0.tokens == r1.tokens, f"request {r0.rid} diverged"
+    # the spans reached the profiler's host plane: one serve.iter event
+    # per loop iteration, one dispatch event per call (warm-up included)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    phase = eng1.metrics.get("serve_phase_s")
+    assert names.count("serve.iter") == phase.count(phase="serve.iter") > 0
+    assert names.count("serve.dispatch.pool_decode") == \
+        eng1.jitwatch.entries["pool_decode"].calls
+
+
+MAX_LEN = 4096
+
+
+def _phase_run():
+    """A traced CIM engine run whose first decode step also collects
+    garbage (and no other step does); returns (engine, chrome events, decode step seconds as
+    serve_decode_step_s observed them)."""
+    cfg = _cfg(cim=True)
+    params = _params(cfg, cim=True)
+    trace = TraceBuffer()
+    # a long pool makes each decode step take milliseconds on the CPU, so
+    # the spans' own microseconds stay well under 1% of it
+    eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=MAX_LEN,
+                                   trace=trace)
+    steps = []
+    observe = eng._h_decode.observe
+
+    def observe_step(v, **labels):
+        steps.append(v)
+        observe(v, **labels)
+    eng._h_decode.observe = observe_step
+    decode = eng._decode_once
+    collected = []
+
+    def decode_collecting(now):
+        if not collected:
+            collected.append(gc.collect())
+        return decode(now)
+    eng._decode_once = decode_collecting
+    # no automatic collections: one landing between a step's dispatch and
+    # wait spans would leave more than 1% of a CPU step uncovered
+    gc.disable()
+    try:
+        eng.run(_requests(cfg, [32, 64, 32], [3, 2, 1]), realtime=False)
+    finally:
+        gc.enable()
+    return eng, trace.to_dict()["traceEvents"], steps
+
+
+def _slices(events, name):
+    return sorted(((ev["ts"], ev["ts"] + ev["dur"], ev) for ev in events
+                   if ev["ph"] == "X" and ev["pid"] == ENGINE_PID
+                   and ev["name"] == name), key=lambda t: t[:2])
+
+
+def test_engine_phase_spans_nest_in_iterations():
+    """Every loop iteration is one serve.iter slice, the parent of the
+    phases inside it; each decode step's dispatch and wait spans lie in
+    the interval serve_decode_step_s timed and cover at least 99% of it;
+    request phases carry their rid; the gc callback is gone after run."""
+    eng, events, steps = _phase_run()
+    assert check_obs.check_trace_schema({"traceEvents": events,
+                                         "displayTimeUnit": "ms"})
+    assert check_obs.check_phase_nesting({"traceEvents": events}) > 0
+    iters = _slices(events, "serve.iter")
+    phase = eng.metrics.get("serve_phase_s")
+    assert len(iters) == phase.count(phase="serve.iter")
+    assert len(_slices(events, "serve.schedule")) == len(iters)
+    names = {ev["name"] for ev in events
+             if ev["ph"] == "X" and ev["pid"] == ENGINE_PID}
+    assert names == {
+        "serve.iter", "serve.schedule", "serve.admit", "serve.prefill",
+        "serve.prefill.wait", "serve.prefill.readback", "serve.decode",
+        "serve.decode.wait", "serve.decode.readback", "serve.decode.emit",
+        "serve.finish", "serve.gc", "serve.dispatch.slot_reset",
+        "serve.dispatch.slot_prefill", "serve.dispatch.pool_decode",
+        "serve.dispatch.slot_activate"}
+    # the forced collection ran inside an iteration
+    ((g0, g1, gc_ev),) = _slices(events, "serve.gc")
+    assert gc_ev["args"]["generation"] == 2
+    assert any(s <= g0 and g1 <= e for s, e, _ in iters)
+    for name in ("serve.admit", "serve.prefill", "serve.finish"):
+        assert all("rid" in ev["args"] for _, _, ev in _slices(events, name))
+    decodes = _slices(events, "serve.decode")
+    dispatches = _slices(events, "serve.dispatch.pool_decode")
+    waits = _slices(events, "serve.decode.wait")
+    assert len(decodes) == len(dispatches) == len(waits) == len(steps) > 0
+    for (d0, d1, dec), (p0, p1, _), (w0, w1, _), dt in zip(
+            decodes, dispatches, waits, steps):
+        assert dec["args"]["live"] >= 1
+        assert d0 <= p0 <= p1 <= w0 <= w1 <= d1
+        dt_us = dt * 1e6
+        assert w1 - p0 <= dt_us + 1e-3
+        assert (p1 - p0) + (w1 - w0) >= 0.99 * dt_us
+    assert not any(getattr(cb, "__self__", None) is eng.tracer
+                   for cb in gc.callbacks)
+
+
+def test_check_obs_refuses_a_phase_outside_iterations():
+    doc = {"traceEvents": [
+        {"ph": "X", "name": "serve.iter", "pid": 1, "tid": 0, "ts": 0.0,
+         "dur": 10.0, "args": {"dur_s": 1e-5}},
+        {"ph": "X", "name": "serve.decode", "pid": 1, "tid": 0, "ts": 2.0,
+         "dur": 5.0, "args": {"dur_s": 5e-6}},
+        {"ph": "X", "name": "serve.gc", "pid": 1, "tid": 0, "ts": 10.5,
+         "dur": 1.0, "args": {"dur_s": 1e-6}}]}
+    assert check_obs.check_phase_nesting(doc) == 1
+    doc["traceEvents"].append(
+        {"ph": "X", "name": "serve.finish", "pid": 1, "tid": 0, "ts": 9.0,
+         "dur": 2.0, "args": {"dur_s": 2e-6}})
+    with pytest.raises(check_obs.CheckError, match="serve.finish"):
+        check_obs.check_phase_nesting(doc)
 
 
 # ------------------------------------------- multi-process export/merge

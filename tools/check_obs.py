@@ -12,7 +12,10 @@ round trip an external dashboard would do:
      whose count equals the exact count.
   2. Chrome-trace JSON (obs/trace.TraceBuffer.to_dict): a traceEvents
      list of X/i/C/M phase events with the fields Perfetto needs; every
-     "X" span carries its exact seconds in args.dur_s.
+     "X" span carries its exact seconds in args.dur_s; the engine's
+     `serve.*` phase slices nest inside a `serve.iter` slice of their
+     track (`serve.gc` excepted: a collection starts at whichever
+     allocation triggers it, between iterations too).
   3. Serving invariants: the one-decode-trace contract
      (jit_traces{entry="pool_decode"} == 1 — the PR 7 retrace bug class,
      lint R001's runtime twin; on a merged multi-rank export the check
@@ -32,10 +35,15 @@ Usage (exits non-zero on the first violated check):
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import sys
 
 TRACE_PHASES = {"X", "i", "C", "M"}
+PHASE_PREFIX = "serve."
+ITER_PHASE = "serve.iter"
+FREE_PHASES = {ITER_PHASE, "serve.gc"}   # need no enclosing serve.iter
+NEST_SLACK_US = 1e-3                     # float rounding of ts + dur
 
 
 class CheckError(Exception):
@@ -196,6 +204,33 @@ def check_trace_schema(doc: dict) -> int:
     return len(events)
 
 
+def check_phase_nesting(doc: dict) -> int:
+    """Every `serve.*` phase slice lies inside a `serve.iter` slice of the
+    same track. Returns the number of phase slices checked."""
+    spans = [ev for ev in doc["traceEvents"] if ev.get("ph") == "X"
+             and ev["name"].startswith(PHASE_PREFIX)]
+    iters: dict = {}
+    for ev in spans:
+        if ev["name"] == ITER_PHASE:
+            iters.setdefault((ev["pid"], ev.get("tid")), []).append(
+                (ev["ts"], ev["ts"] + ev["dur"]))
+    for track in iters.values():
+        track.sort()
+    n = 0
+    for ev in spans:
+        if ev["name"] in FREE_PHASES:
+            continue
+        track = iters.get((ev["pid"], ev.get("tid")), [])
+        i = bisect.bisect_right(track, (ev["ts"] + NEST_SLACK_US,
+                                        float("inf"))) - 1
+        _require(i >= 0 and ev["ts"] + ev["dur"]
+                 <= track[i][1] + NEST_SLACK_US,
+                 f"trace: phase {ev['name']} at ts {ev['ts']} lies "
+                 f"outside every {ITER_PHASE} slice of its track")
+        n += 1
+    return n
+
+
 # ----------------------------------------------------------------- cli
 
 def main(argv=None) -> int:
@@ -220,11 +255,12 @@ def main(argv=None) -> int:
         if not args.no_decode_contract:
             check_decode_contract(metrics, expect_ranks=args.expect_ranks)
         n_chips = check_energy_reconciliation(metrics)
-        n_events = 0
+        n_events = n_phases = 0
         if args.trace:
             with open(args.trace) as f:
                 trace = json.load(f)
             n_events = check_trace_schema(trace)
+            n_phases = check_phase_nesting(trace)
     except CheckError as e:
         print(f"check_obs: FAIL: {e}", file=sys.stderr)
         return 1
@@ -232,7 +268,8 @@ def main(argv=None) -> int:
            + ("" if args.no_decode_contract
               else ", decode trace contract holds"))
     if args.trace:
-        msg += f", {n_events} trace events well-formed"
+        msg += (f", {n_events} trace events well-formed, {n_phases} "
+                "phase slices nested in serve.iter")
     print(msg)
     return 0
 
