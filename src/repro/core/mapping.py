@@ -66,9 +66,11 @@ Execution comes in two forms:
 
 A `PackedPlan` is a pytree whose geometry (tile index maps, block sizes,
 pass structure) is static aux data: packed plans of a scanned layer stack
-can be stacked with `tree_map(jnp.stack, ...)` and sliced inside `lax.scan`
-without retracing. At datacenter scale the planner operates per TP shard (a
-'core' is the intra-shard unit; see distributed/sharding.shard_shape).
+can be stacked with `tree_map(jnp.stack, ...)` and scanned without
+retracing, each layer's kernels reading their tiles in place from the
+stack (`split_tile_stacks`). At datacenter scale the planner operates per
+TP shard (a 'core' is the intra-shard unit; see
+distributed/sharding.shard_shape).
 """
 from __future__ import annotations
 
@@ -340,6 +342,17 @@ class PackedPlan:
                       and execution routes to the transpose-direction kernel,
                       which contracts each tile on its stored COLUMN axis —
                       the TNSA's BL->SL access of the same programmed cells.
+
+    Reading a tile stack in place (`split_tile_stacks` / `join_tile_stacks`):
+      stack_index:    None for a plan that owns its tiles. Otherwise gd_tiles
+                      is a whole scanned stack (*S, T, bk, bn) and stack_index
+                      an int32 array over the leading dims the plan still has
+                      (() once one layer and shard remain) holding flat
+                      positions in S. Scans and shard loops index stack_index
+                      and leave gd_tiles whole (`take`); the kernels merge S
+                      into one axis (a bitcast) and start each tile's DMA at
+                      the prefetched position, so no layer's tiles are copied
+                      out of the stack before the kernel reads them.
     """
     layer: str
     bk: int
@@ -358,6 +371,7 @@ class PackedPlan:
     inv_norm_tiles: jax.Array
     v_decr_tiles: jax.Array
     denorm_tiles: jax.Array
+    stack_index: Optional[jax.Array] = None
 
     @property
     def n_tiles(self) -> int:
@@ -377,7 +391,7 @@ class PackedPlan:
 
     def tree_flatten(self):
         children = (self.gd_tiles, self.inv_norm_tiles, self.v_decr_tiles,
-                    self.denorm_tiles)
+                    self.denorm_tiles, self.stack_index)
         aux = (self.layer, self.bk, self.bn, self.n_rows, self.n_cols,
                self.row_block, self.col_block, self.seq_slot, self.n_passes,
                self.transpose, self.tile_slot, self.out_slot, self.out_col)
@@ -386,6 +400,79 @@ class PackedPlan:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*aux, *children)
+
+
+def _is_plan(x) -> bool:
+    return isinstance(x, PackedPlan)
+
+
+def split_tile_stacks(tree):
+    """Take every packed plan's tile stack out of a layer-stack tree.
+
+    Returns (tree, stacks): each plan keeps its small per-tile arrays and
+    gains a stack_index over its leading dims (flat positions in the stack;
+    a plan that already has one keeps it), and its gd_tiles leave the tree
+    for the `stacks` list. Scan the returned tree and close over `stacks`:
+    the scan then slices only the index, and `join_tile_stacks` hands each
+    layer's plans the whole stack back (`transformer.scan_layers`)."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree, is_leaf=_is_plan)
+    stacks = []
+    for i, leaf in enumerate(leaves):
+        if isinstance(leaf, PackedPlan):
+            idx = leaf.stack_index
+            if idx is None:
+                lead = leaf.gd_tiles.shape[:-3]
+                idx = jnp.arange(math.prod(lead), dtype=jnp.int32) \
+                    .reshape(lead)
+            stacks.append(leaf.gd_tiles)
+            leaves[i] = dataclasses.replace(leaf, gd_tiles=None,
+                                            stack_index=idx)
+    return treedef.unflatten(leaves), stacks
+
+
+def join_tile_stacks(tree, stacks):
+    """Inverse of `split_tile_stacks` on a tree of the same structure (a
+    scan step's slice of it included): plan i gets stacks[i] back whole."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree, is_leaf=_is_plan)
+    it = iter(stacks)
+    leaves = [dataclasses.replace(leaf, gd_tiles=next(it))
+              if isinstance(leaf, PackedPlan) else leaf for leaf in leaves]
+    return treedef.unflatten(leaves)
+
+
+def take(tree, i):
+    """`tree[i]` on the leading dim of every array in `tree`, except that a
+    stack-indexed plan keeps its tile stack whole: its stack_index is
+    indexed instead (the shard and expert loops over packed plans)."""
+    def one(a):
+        if isinstance(a, PackedPlan) and a.stack_index is not None:
+            sub = jax.tree_util.tree_map(
+                lambda x: x[i], dataclasses.replace(a, gd_tiles=None))
+            return dataclasses.replace(sub, gd_tiles=a.gd_tiles)
+        return jax.tree_util.tree_map(lambda x: x[i], a)
+    return jax.tree_util.tree_map(one, tree, is_leaf=_is_plan)
+
+
+def slice_tile_stacks(tree):
+    """Give every stack-indexed plan in `tree` its own tiles again: the
+    slice of the stack its stack_index spans, and no index. For executors
+    that split a plan's leading dims across devices (shard_map), where the
+    stack's shard dim must stay a dim of its own.
+
+    stack_index spans the trailing dims of the stack's leading dims whole
+    (scans and `take` only ever index its leading dim), so its first entry
+    divided by its size is the position of that slice."""
+    def one(a):
+        if not isinstance(a, PackedPlan) or a.stack_index is None:
+            return a
+        idx = a.stack_index
+        gd = a.gd_tiles.reshape((-1,) + idx.shape + a.gd_tiles.shape[-3:])
+        start = idx.reshape(-1)[0] // idx.size
+        return dataclasses.replace(
+            a, gd_tiles=jax.lax.dynamic_index_in_dim(gd, start, 0,
+                                                     keepdims=False),
+            stack_index=None)
+    return jax.tree_util.tree_map(one, tree, is_leaf=_is_plan)
 
 
 def _slot_order(tiles: Sequence[Tile], schedule: Optional[TileSchedule]

@@ -57,6 +57,15 @@ paper Fig. 4e-g — the BL->SL read of the same programmed cells):
     the per-run fallback fold in the wrapper, exactly like the scheduled
     kernel.
 
+All three packed kernels read their tiles from a STACK `gd (S, T, bk, bn)`
+at a scalar-prefetched position `stack_index`: a plan that owns its tiles
+is a stack of one (S = 1, position 0); a layer of a scanned stack passes
+the whole stack of every layer and shard, merged into S by a free reshape,
+and its flat position in it (core/mapping.split_tile_stacks). The tile
+BlockSpec's index map starts each DMA at (stack_index, tile); the kernel
+bodies, grids and the bytes they read are the same either way, and the
+layer's tiles are never copied out of the stack first.
+
 The stochastic-activation (LFSR comparator-bit) path is supported in ALL
 packed kernels: counts are neuron-unit bits, so the kernels weight them by
 the valid-column mask (invn > 0) instead of the fold_norm denorm — one pack
@@ -212,8 +221,14 @@ def cim_mvm_pallas(x, gd, inv_norm, v_decr, seed, *, activation: str = "none",
 
 # ----------------------------------------------------------- packed executor
 
-def _cim_packed_kernel(row_ref, col_ref, x_ref, gd_ref, invn_ref, den_ref,
-                       vd_ref, seed_ref, out_ref, *, v_read: float,
+def _stack_position(stack_index):
+    """The (1,) int32 scalar-prefetch operand holding a plan's position in
+    its tile stack (the first index of the tile BlockSpec's index map)."""
+    return jnp.asarray(stack_index, jnp.int32).reshape(1)
+
+
+def _cim_packed_kernel(pos_ref, row_ref, col_ref, x_ref, gd_ref, invn_ref,
+                       den_ref, vd_ref, seed_ref, out_ref, *, v_read: float,
                        activation: str, n_max: int):
     """One grid step = one (batch block, tile) pair.
 
@@ -243,21 +258,22 @@ def _cim_packed_kernel(row_ref, col_ref, x_ref, gd_ref, invn_ref, den_ref,
     static_argnames=("row_block", "col_block", "activation", "n_max",
                      "v_read", "bm", "interpret"))
 def cim_mvm_packed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
-                          v_decr_tiles, seed, *,
+                          v_decr_tiles, seed, stack_index, *,
                           row_block, col_block, activation: str = "none",
                           n_max: int = 127, v_read: float = 0.5,
                           bm: int = 256, interpret: bool = False):
     """Whole-layer packed CIM MVM: ONE pallas_call over every tile.
 
     x:(M,K) f32 integer-valued activations (K = layer weight rows);
-    gd_tiles:(T,bk,bn); inv_norm_tiles/denorm_tiles:(T,1,bn);
-    v_decr_tiles:(T,); row_block/col_block: static tile->block index tuples
-    (scalar-prefetched into the kernel's index maps). Returns
-    (M_padded, n_col_blocks*bn) f32 — caller slices to (M, C).
+    gd_tiles:(S,T,bk,bn) a tile stack, read at scalar position
+    stack_index; inv_norm_tiles/denorm_tiles:(T,1,bn); v_decr_tiles:(T,);
+    row_block/col_block: static tile->block index tuples (scalar-prefetched
+    into the kernel's index maps). Returns (M_padded, n_col_blocks*bn) f32
+    — caller slices to (M, C).
     """
     TRACE_COUNTS["cim_mvm_packed"] += 1
     m, kdim = x.shape
-    n_tiles, bk, bn = gd_tiles.shape
+    _, n_tiles, bk, bn = gd_tiles.shape
     bm = min(bm, m)
     n_row_blocks = max(row_block) + 1
     n_col_blocks = max(col_block) + 1
@@ -275,17 +291,19 @@ def cim_mvm_packed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     col_idx = jnp.asarray(col_block, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(mp // bm, n_tiles),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, t, row, col: (i, row[t])),
-            pl.BlockSpec((1, bk, bn), lambda i, t, row, col: (t, 0, 0)),
-            pl.BlockSpec((1, 1, bn), lambda i, t, row, col: (t, 0, 0)),
-            pl.BlockSpec((1, 1, bn), lambda i, t, row, col: (t, 0, 0)),
+            pl.BlockSpec((bm, bk), lambda i, t, pos, row, col: (i, row[t])),
+            pl.BlockSpec((None, 1, bk, bn),
+                         lambda i, t, pos, row, col: (pos[0], t, 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, t, pos, row, col: (t, 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, t, pos, row, col: (t, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, t, row, col: (i, col[t])),
+        out_specs=pl.BlockSpec((bm, bn),
+                               lambda i, t, pos, row, col: (i, col[t])),
     )
     return pl.pallas_call(
         functools.partial(_cim_packed_kernel, v_read=v_read,
@@ -293,14 +311,15 @@ def cim_mvm_packed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n_col_blocks * bn), jnp.float32),
         interpret=interpret,
-    )(row_idx, col_idx, xp, gd_tiles, inv_norm_tiles, denorm_tiles,
+    )(_stack_position(stack_index), row_idx, col_idx, xp, gd_tiles,
+      inv_norm_tiles, denorm_tiles,
       v_decr_tiles.astype(jnp.float32),
       jnp.asarray(seed, jnp.int32).reshape(1))
 
 
 # --------------------------------------------------------- scheduled executor
 
-def _cim_sched_kernel(row_ref, outs_ref, x_ref, gd_ref, invn_ref,
+def _cim_sched_kernel(pos_ref, row_ref, outs_ref, x_ref, gd_ref, invn_ref,
                       den_ref, vd_ref, seed_ref, out_ref, *, pass_len: int,
                       v_read: float, activation: str, n_max: int):
     """One grid step = one (batch block, pass, core slot) triple.
@@ -356,16 +375,17 @@ def _fold_runs(parts, out_col, bn, mp):
     static_argnames=("row_block", "out_slot", "out_col", "n_passes",
                      "activation", "n_max", "v_read", "bm", "interpret"))
 def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
-                             v_decr_tiles, seed, *,
+                             v_decr_tiles, seed, stack_index, *,
                              row_block, out_slot, out_col, n_passes: int,
                              activation: str = "none", n_max: int = 127,
                              v_read: float = 0.5, bm: int = 256,
                              interpret: bool = False):
     """Whole-layer scheduled CIM MVM: ONE pallas_call over a pass-major grid.
 
-    x:(M,K) f32 integer-valued activations; gd_tiles:(P*S,bk,bn) pass-major
-    slot tensors in FUSED order (each pass sorted by output block, idle
-    slots zeroed at the pass tail); inv_norm_tiles/denorm_tiles:(P*S,1,bn);
+    x:(M,K) f32 integer-valued activations; gd_tiles:(S',P*S,bk,bn) a
+    stack of pass-major slot tensors in FUSED order (each pass sorted by
+    output block, idle slots zeroed at the pass tail), read at scalar
+    position stack_index; inv_norm_tiles/denorm_tiles:(P*S,1,bn);
     v_decr_tiles:(P*S,); row_block: static per-slot input block tuple;
     out_slot/out_col: the fused run layout (slot -> run, run -> column
     block; core/mapping._fused_layout). row_block and out_slot are
@@ -376,7 +396,7 @@ def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     """
     TRACE_COUNTS["cim_mvm_scheduled"] += 1
     m, kdim = x.shape
-    n_slots, bk, bn = gd_tiles.shape
+    _, n_slots, bk, bn = gd_tiles.shape
     pass_len = n_slots // n_passes
     bm = min(bm, m)
     n_row_blocks = max(row_block) + 1
@@ -395,20 +415,20 @@ def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     out_idx = jnp.asarray(out_slot, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(mp // bm, n_passes, pass_len),
         in_specs=[
             pl.BlockSpec((bm, bk),
-                         lambda i, p, s, row, outs:
+                         lambda i, p, s, pos, row, outs:
                          (i, row[p * pass_len + s])),
-            pl.BlockSpec((1, bk, bn),
-                         lambda i, p, s, row, outs:
+            pl.BlockSpec((None, 1, bk, bn),
+                         lambda i, p, s, pos, row, outs:
+                         (pos[0], p * pass_len + s, 0, 0)),
+            pl.BlockSpec((1, 1, bn),
+                         lambda i, p, s, pos, row, outs:
                          (p * pass_len + s, 0, 0)),
             pl.BlockSpec((1, 1, bn),
-                         lambda i, p, s, row, outs:
-                         (p * pass_len + s, 0, 0)),
-            pl.BlockSpec((1, 1, bn),
-                         lambda i, p, s, row, outs:
+                         lambda i, p, s, pos, row, outs:
                          (p * pass_len + s, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -417,7 +437,7 @@ def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
         # its VMEM stays live across exactly the visits that accumulate
         # into it (the Pallas TPU consecutive-revisit invariant).
         out_specs=pl.BlockSpec((bm, bn),
-                               lambda i, p, s, row, outs:
+                               lambda i, p, s, pos, row, outs:
                                (i, outs[p * pass_len + s])),
     )
     parts = pl.pallas_call(
@@ -426,7 +446,8 @@ def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n_runs * bn), jnp.float32),
         interpret=interpret,
-    )(row_idx, out_idx, xp, gd_tiles, inv_norm_tiles, denorm_tiles,
+    )(_stack_position(stack_index), row_idx, out_idx, xp, gd_tiles,
+      inv_norm_tiles, denorm_tiles,
       v_decr_tiles.astype(jnp.float32),
       jnp.asarray(seed, jnp.int32).reshape(1))
     return _fold_runs(parts, out_col, bn, mp)
@@ -434,8 +455,8 @@ def cim_mvm_scheduled_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
 
 # -------------------------------------------------- transpose-direction executor
 
-def _cim_transposed_kernel(in_ref, stk_ref, outs_ref, x_ref, gd_ref, invn_ref,
-                           den_ref, vd_ref, seed_ref, out_ref, *,
+def _cim_transposed_kernel(pos_ref, in_ref, stk_ref, outs_ref, x_ref, gd_ref,
+                           invn_ref, den_ref, vd_ref, seed_ref, out_ref, *,
                            v_read: float, activation: str, n_max: int):
     """One grid step = one (batch block, tile slot) pair, transpose direction.
 
@@ -472,7 +493,7 @@ def _cim_transposed_kernel(in_ref, stk_ref, outs_ref, x_ref, gd_ref, invn_ref,
     static_argnames=("in_block", "tile_slot", "out_slot", "out_col",
                      "activation", "n_max", "v_read", "bm", "interpret"))
 def cim_mvm_transposed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
-                              v_decr_tiles, seed, *,
+                              v_decr_tiles, seed, stack_index, *,
                               in_block, tile_slot, out_slot, out_col,
                               activation: str = "none",
                               n_max: int = 127, v_read: float = 0.5,
@@ -481,8 +502,9 @@ def cim_mvm_transposed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     SHARED forward tile stack, contracted on the stored column axis.
 
     x:(M, K') f32 integer-valued activations (K' = the layer's weight
-    COLUMNS — the transpose direction's input space); gd_tiles:(T,bk,bn)
-    the forward stack, unchanged and uncopied; inv_norm_tiles /
+    COLUMNS — the transpose direction's input space); gd_tiles:(S,T,bk,bn)
+    a stack of forward tile tensors, unchanged and uncopied, read at scalar
+    position stack_index; inv_norm_tiles /
     denorm_tiles:(T,1,bk) transpose-direction per-ROW tensors in THIS
     direction's fused grid order (`pack_tiles_transposed`);
     v_decr_tiles:(T,) that direction's ADC steps. in_block: static per-slot
@@ -495,7 +517,7 @@ def cim_mvm_transposed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     """
     TRACE_COUNTS["cim_mvm_transposed"] += 1
     m, kdim = x.shape
-    n_slots, bko, bni = gd_tiles.shape     # stored fwd layout: out/in swap
+    _, n_slots, bko, bni = gd_tiles.shape  # stored fwd layout: out/in swap
     bm = min(bm, m)
     n_in_blocks = max(in_block) + 1
     n_runs = len(out_col)
@@ -514,22 +536,24 @@ def cim_mvm_transposed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
     out_idx = jnp.asarray(out_slot, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(mp // bm, n_slots),
         in_specs=[
             pl.BlockSpec((bm, bni),
-                         lambda i, t, inb, stk, outs: (i, inb[t])),
-            pl.BlockSpec((1, bko, bni),
-                         lambda i, t, inb, stk, outs: (stk[t], 0, 0)),
+                         lambda i, t, pos, inb, stk, outs: (i, inb[t])),
+            pl.BlockSpec((None, 1, bko, bni),
+                         lambda i, t, pos, inb, stk, outs:
+                         (pos[0], stk[t], 0, 0)),
             pl.BlockSpec((1, 1, bko),
-                         lambda i, t, inb, stk, outs: (t, 0, 0)),
+                         lambda i, t, pos, inb, stk, outs: (t, 0, 0)),
             pl.BlockSpec((1, 1, bko),
-                         lambda i, t, inb, stk, outs: (t, 0, 0)),
+                         lambda i, t, pos, inb, stk, outs: (t, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bko),
-                               lambda i, t, inb, stk, outs: (i, outs[t])),
+                               lambda i, t, pos, inb, stk, outs:
+                               (i, outs[t])),
     )
     parts = pl.pallas_call(
         functools.partial(_cim_transposed_kernel, v_read=v_read,
@@ -537,7 +561,8 @@ def cim_mvm_transposed_pallas(x, gd_tiles, inv_norm_tiles, denorm_tiles,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n_runs * bko), jnp.float32),
         interpret=interpret,
-    )(in_idx, stk_idx, out_idx, xp, gd_tiles, inv_norm_tiles, denorm_tiles,
+    )(_stack_position(stack_index), in_idx, stk_idx, out_idx, xp, gd_tiles,
+      inv_norm_tiles, denorm_tiles,
       v_decr_tiles.astype(jnp.float32),
       jnp.asarray(seed, jnp.int32).reshape(1))
     return _fold_runs(parts, out_col, bko, mp)

@@ -23,6 +23,11 @@ small post-dispatch fold. `fused=False` forces the per-slot-partial layout
 (one partial block per slot, whole reduction after the dispatch) — the
 pre-fusion baseline, kept for benchmarking the win and for parity tests.
 
+A plan either owns its tiles or reads them in place from a whole layer
+stack at its `stack_index` (core/mapping.split_tile_stacks): the kernels
+take both as a stack and a position (`_tile_stack`), the constant 0 for a
+plan owning its tiles.
+
 The batch block shape defaults to the autotuner's cached winner for the
 plan's signature (`autotune.lookup`; 256 until `autotune.tune` has measured
 the shape) — pass bm explicitly to pin it.
@@ -43,6 +48,34 @@ from .kernel import (cim_mvm_pallas, cim_mvm_packed_pallas,
 
 if TYPE_CHECKING:      # see ref.py: no core import while kernels load
     from ...core.types import CIMConfig
+
+
+# The packed kernels, by the name their jit carries into a jaxpr, and the
+# place of `stack_index` among their array arguments (launch/scheduler.
+# packed_dispatches reads both).
+PACKED_KERNELS = ("cim_mvm_packed_pallas", "cim_mvm_scheduled_pallas",
+                  "cim_mvm_transposed_pallas")
+STACK_INDEX_ARG = 6
+
+
+def _tile_stack(packed):
+    """(gd (S, T, bk, bn), position) of a plan's tiles. A plan owning its
+    tiles is a stack of one at position 0; a stack-indexed plan passes its
+    whole stack, leading dims merged (a reshape that moves no bytes)."""
+    gd = packed.gd_tiles
+    if packed.stack_index is None:
+        if gd.ndim != 3:
+            raise ValueError(
+                f"plan '{packed.layer}' has tiles of shape {gd.shape}: "
+                "index one layer (and shard) first, or split its stack "
+                "(core/mapping.split_tile_stacks)")
+        return gd[None], 0
+    if jnp.ndim(packed.stack_index) != 0:
+        raise ValueError(
+            f"plan '{packed.layer}' still spans stack positions of shape "
+            f"{jnp.shape(packed.stack_index)}: take one layer and shard "
+            "first (core/mapping.take)")
+    return gd.reshape((-1,) + gd.shape[-3:]), packed.stack_index
 
 
 def default_interpret() -> bool:
@@ -109,6 +142,7 @@ def packed_call(x, packed, *, activation: str, n_max: int, v_read: float,
         interpret = default_interpret()
     if bm is None:
         bm = autotune.lookup(packed, x.shape[0], activation)
+    gd, pos = _tile_stack(packed)
     n_slots = packed.n_tiles
     out_slot = packed.out_slot if fused else tuple(range(n_slots))
     out_col = packed.out_col if fused else packed.col_block
@@ -116,9 +150,9 @@ def packed_call(x, packed, *, activation: str, n_max: int, v_read: float,
         # transpose-direction plan: one kernel serves any pass structure
         # (runs never straddle a pass's block re-sort — `scheduled` is moot)
         out = cim_mvm_transposed_pallas(
-            x.astype(jnp.float32), packed.gd_tiles, packed.inv_norm_tiles,
+            x.astype(jnp.float32), gd, packed.inv_norm_tiles,
             packed.denorm_tiles, packed.v_decr_tiles,
-            jnp.asarray(seed, jnp.int32),
+            jnp.asarray(seed, jnp.int32), pos,
             in_block=packed.row_block, tile_slot=packed.tile_slot,
             out_slot=out_slot, out_col=out_col,
             activation=activation, n_max=n_max, v_read=v_read, bm=bm,
@@ -132,18 +166,18 @@ def packed_call(x, packed, *, activation: str, n_max: int, v_read: float,
             "the tile-grid kernel cannot serialize merged cores")
     if scheduled:
         out = cim_mvm_scheduled_pallas(
-            x.astype(jnp.float32), packed.gd_tiles, packed.inv_norm_tiles,
+            x.astype(jnp.float32), gd, packed.inv_norm_tiles,
             packed.denorm_tiles, packed.v_decr_tiles,
-            jnp.asarray(seed, jnp.int32),
+            jnp.asarray(seed, jnp.int32), pos,
             row_block=packed.row_block, out_slot=out_slot,
             out_col=out_col, n_passes=packed.n_passes,
             activation=activation, n_max=n_max, v_read=v_read, bm=bm,
             interpret=interpret)
     else:
         out = cim_mvm_packed_pallas(
-            x.astype(jnp.float32), packed.gd_tiles, packed.inv_norm_tiles,
+            x.astype(jnp.float32), gd, packed.inv_norm_tiles,
             packed.denorm_tiles, packed.v_decr_tiles,
-            jnp.asarray(seed, jnp.int32),
+            jnp.asarray(seed, jnp.int32), pos,
             row_block=packed.row_block, col_block=packed.col_block,
             activation=activation, n_max=n_max, v_read=v_read, bm=bm,
             interpret=interpret)

@@ -48,14 +48,17 @@ that hold:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.cim_mvm import ops as cim_ops
 from ..obs import MetricsRegistry, TraceBuffer
 from ..obs.chipmeter import ChipMeter
 from ..obs.clock import now as clock_now
@@ -64,6 +67,48 @@ from ..obs.jitwatch import JitWatcher
 from ..obs.trace import ENGINE_PID, REQUEST_PID, Tracer
 from .steps import (POOL_KEYS, arch_serving, make_pool_decode_step,
                     make_slot_prefill_step)
+
+
+def packed_dispatches(jaxpr, weight: int = 1) -> Dict[str, int]:
+    """The packed CIM dispatches one run of `jaxpr` makes, by how each
+    reads its tiles: 'in_place' at a traced stack position (a layer of a
+    scanned stack, core/mapping.split_tile_stacks) or 'sliced' at the
+    constant position of a plan owning its tiles. A scan's body counts
+    once per trip; the serving steps have no cond, whose branches would
+    each count."""
+    counts = {"in_place": 0, "sliced": 0}
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("name") in cim_ops.PACKED_KERNELS:
+            pos = eqn.invars[cim_ops.STACK_INDEX_ARG]
+            counts["sliced" if isinstance(pos, jex_core.Literal)
+                   else "in_place"] += weight
+            continue
+        trips = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    for read, n in packed_dispatches(
+                            sub, weight * trips).items():
+                        counts[read] += n
+    return counts
+
+
+def count_packed_dispatches(fn, gauge, entry: str):
+    """`fn`, setting `gauge{entry, tile_read}` to its `packed_dispatches`
+    whenever jit traces it. `fn` is traced once, to a jaxpr that is read
+    and then evaluated in its place, so the count costs no second trace
+    and nothing per call."""
+    @functools.wraps(fn)
+    def traced(*args):
+        closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+        for read, n in packed_dispatches(closed.jaxpr).items():
+            gauge.set(n, entry=entry, tile_read=read)
+        out = jax.core.eval_jaxpr(closed.jaxpr, closed.consts,
+                                  *jax.tree_util.tree_leaves(args))
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(out_shape), out)
+    return traced
 
 
 def init_pool(cfg, n_slots: int, max_len: int, mesh=None):
@@ -185,12 +230,18 @@ class ContinuousBatchingEngine:
         # semantics — and the bitwise pool-vs-static contract — are
         # untouched whether metrics are read or not.
         self.jitwatch = JitWatcher(strict=strict_jit, tracer=self.tracer)
+        g_packed = self.metrics.gauge(
+            "serve_packed_dispatches",
+            "packed CIM dispatches per step call, by tile_read: in_place "
+            "(indexing the layer stack) or sliced (a plan of its own)")
         self._decode = self.jitwatch.wrap(
-            "pool_decode", make_pool_decode_step(cfg), max_traces=1,
-            donate_argnums=(1,),
+            "pool_decode", count_packed_dispatches(
+                make_pool_decode_step(cfg), g_packed, "pool_decode"),
+            max_traces=1, donate_argnums=(1,),
             **({"out_shardings": (None, ns)} if ns is not None else {}))
         self._prefill = self.jitwatch.wrap(
-            "slot_prefill", make_slot_prefill_step(cfg),
+            "slot_prefill", count_packed_dispatches(
+                make_slot_prefill_step(cfg), g_packed, "slot_prefill"),
             donate_argnums=(1,),
             **({"out_shardings": (None, ns)} if ns is not None else {}))
         self._reset = self.jitwatch.wrap(

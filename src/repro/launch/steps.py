@@ -175,7 +175,7 @@ def _prefix_embeds(params, cache, emb, cfg):
                                     cache_len=pos)
         return y, (nk, nv)
 
-    x, (nks, nvs) = jax.lax.scan(
+    x, (nks, nvs) = T.scan_layers(
         body, emb.astype(cfg.dtype),
         (params["layers"], cache["k"], cache["v"],
          jnp.arange(cfg.n_layers)),

@@ -26,6 +26,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from ..core.mapping import join_tile_stacks, split_tile_stacks
+
 
 def layer_params(key, cfg, dtype) -> Dict:
     d = cfg.d_model
@@ -129,7 +131,7 @@ def forward(params, x, cfg, positions):
     weight-shared attention block after each full group (deterministic group
     structure — no lax.cond — so dry-run cost extrapolation stays linear).
     Remainder layers (n_layers % every) run without a trailing attn block."""
-    from .transformer import rms_norm, dense_block, routed_mlp
+    from .transformer import rms_norm, dense_block, routed_mlp, scan_layers
     every = cfg.hybrid_attn_every or cfg.n_layers
 
     from .transformer import _remat_policy
@@ -144,26 +146,21 @@ def forward(params, x, cfg, positions):
 
     n_groups = cfg.n_layers // every
     n_rem = cfg.n_layers - n_groups * every
-    grouped = jax.tree_util.tree_map(
-        lambda a: a[:n_groups * every].reshape((n_groups, every)
-                                               + a.shape[1:]),
-        params["layers"])
+    grouped, rem = _layer_groups(params["layers"], n_groups, every)
 
     def group_body(x, pg):
-        x, _ = jax.lax.scan(mamba_body, x, pg,
-                            unroll=every if cfg.scan_unroll else 1)
+        x, _ = scan_layers(mamba_body, x, pg,
+                           unroll=every if cfg.scan_unroll else 1)
         if cfg.hybrid_attn_every > 0:
             x, _ = dense_block(params["shared_attn"], x, cfg,
                                positions=positions, layer_idx=0)
         return x, None
 
-    x, _ = jax.lax.scan(group_body, x, grouped,
-                        unroll=n_groups if cfg.scan_unroll else 1)
+    x, _ = scan_layers(group_body, x, grouped,
+                       unroll=n_groups if cfg.scan_unroll else 1)
     if n_rem:
-        rem = jax.tree_util.tree_map(lambda a: a[n_groups * every:],
-                                     params["layers"])
-        x, _ = jax.lax.scan(mamba_body, x, rem,
-                            unroll=n_rem if cfg.scan_unroll else 1)
+        x, _ = scan_layers(mamba_body, x, rem,
+                           unroll=n_rem if cfg.scan_unroll else 1)
     return x
 
 
@@ -177,6 +174,19 @@ def _dummy_kv(cfg, n_groups, b):
     and decode-consumed state drift apart."""
     z = jnp.zeros((n_groups, b, 1, 1, 1), cfg.dtype)
     return z, z
+
+
+def _layer_groups(layers, n_groups: int, every: int):
+    """(grouped, rest): the layer stack cut into (n_groups, every, ...) and
+    the remaining layers. Packed plans keep reading the whole tile stack
+    in place (core/mapping.split_tile_stacks): only their stack indices
+    are cut, so no group or remainder copies a layer's tiles."""
+    layers, stacks = split_tile_stacks(layers)
+    cut = n_groups * every
+    grouped = jax.tree_util.tree_map(
+        lambda a: a[:cut].reshape((n_groups, every) + a.shape[1:]), layers)
+    rest = jax.tree_util.tree_map(lambda a: a[cut:], layers)
+    return join_tile_stacks(grouped, stacks), join_tile_stacks(rest, stacks)
 
 
 def init_state(cfg, batch, max_len, dtype):
@@ -200,7 +210,7 @@ def prefill(params, state, tokens, cfg):
     """Stateful chunked prefill: fills the SSM states and (for the hybrid)
     the shared-attn KV caches over the whole prompt; returns last logits."""
     from .transformer import rms_norm, dense_block, routed_mlp, _softcap, \
-        constrain_batch
+        constrain_batch, scan_layers
     x = params["embed"][tokens].astype(cfg.dtype)        # (B,T,d)
     b, t, d = x.shape
     every = cfg.hybrid_attn_every or cfg.n_layers
@@ -218,10 +228,7 @@ def prefill(params, state, tokens, cfg):
 
     n_groups = cfg.n_layers // every
     n_rem = cfg.n_layers - n_groups * every
-    grouped = jax.tree_util.tree_map(
-        lambda a: a[:n_groups * every].reshape((n_groups, every)
-                                               + a.shape[1:]),
-        params["layers"])
+    grouped, rem_p = _layer_groups(params["layers"], n_groups, every)
     h_grouped = state["h"][:n_groups * every].reshape(
         (n_groups, every) + state["h"].shape[1:])
     if cfg.hybrid_attn_every > 0:
@@ -231,8 +238,8 @@ def prefill(params, state, tokens, cfg):
 
     def group_body(x, inp):
         pg, hg, ck, cv = inp
-        x, h_new = jax.lax.scan(mamba_body, x, (pg, hg),
-                                unroll=every if cfg.scan_unroll else 1)
+        x, h_new = scan_layers(mamba_body, x, (pg, hg),
+                               unroll=every if cfg.scan_unroll else 1)
         nk = nv = ck
         if cfg.hybrid_attn_every > 0:
             x, (nk, nv) = dense_block(params["shared_attn"], x, cfg,
@@ -240,16 +247,14 @@ def prefill(params, state, tokens, cfg):
                                       cache=(ck, cv), cache_len=pos0)
         return x, (h_new, nk, nv)
 
-    x, (h_all, nak, nav) = jax.lax.scan(
+    x, (h_all, nak, nav) = scan_layers(
         group_body, x, (grouped, h_grouped, ak, av),
         unroll=n_groups if cfg.scan_unroll else 1)
     h_all = h_all.reshape((n_groups * every,) + state["h"].shape[1:])
     if n_rem:
-        rem_p = jax.tree_util.tree_map(lambda a: a[n_groups * every:],
-                                       params["layers"])
-        x, h_rem = jax.lax.scan(mamba_body, x,
-                                (rem_p, state["h"][n_groups * every:]),
-                                unroll=n_rem if cfg.scan_unroll else 1)
+        x, h_rem = scan_layers(mamba_body, x,
+                               (rem_p, state["h"][n_groups * every:]),
+                               unroll=n_rem if cfg.scan_unroll else 1)
         h_all = jnp.concatenate([h_all, h_rem], axis=0)
     x = rms_norm(x[:, -1], params["ln_f"])
     unemb = params["embed"].T if cfg.tie_embeddings else params["unembed"]
@@ -264,7 +269,7 @@ def decode_step(params, state, tokens, cfg):
     """Group-structured decode mirroring forward(): `every` mamba steps then
     the shared attention block (with its own KV cache slice per group)."""
     from .transformer import rms_norm, dense_block, routed_mlp, \
-        routed_linear, _softcap
+        routed_linear, _softcap, scan_layers
     x = params["embed"][tokens[:, 0]].astype(cfg.dtype)   # (B,d)
     b, d = x.shape
     d_in = 2 * d
@@ -299,18 +304,15 @@ def decode_step(params, state, tokens, cfg):
 
     n_groups = cfg.n_layers // every
     n_rem = cfg.n_layers - n_groups * every
-    grouped = jax.tree_util.tree_map(
-        lambda a: a[:n_groups * every].reshape((n_groups, every)
-                                               + a.shape[1:]),
-        params["layers"])
+    grouped, rem_p = _layer_groups(params["layers"], n_groups, every)
     h_grouped = state["h"][:n_groups * every].reshape(
         (n_groups, every) + state["h"].shape[1:])
 
     def group_body(carry, inp):
         x, = carry
         pg, hg, ck, cv = inp
-        x, h_new = jax.lax.scan(mamba_step, x, (pg, hg),
-                                unroll=every if cfg.scan_unroll else 1)
+        x, h_new = scan_layers(mamba_step, x, (pg, hg),
+                               unroll=every if cfg.scan_unroll else 1)
         nk = nv = ck
         if cfg.hybrid_attn_every > 0:
             y, (nk, nv) = dense_block(params["shared_attn"], x[:, None], cfg,
@@ -323,16 +325,14 @@ def decode_step(params, state, tokens, cfg):
         ak, av = state["ak"], state["av"]
     else:
         ak, av = _dummy_kv(cfg, n_groups, b)
-    (x,), (h_all, nak, nav) = jax.lax.scan(
+    (x,), (h_all, nak, nav) = scan_layers(
         group_body, (x,), (grouped, h_grouped, ak, av),
         unroll=n_groups if cfg.scan_unroll else 1)
     h_all = h_all.reshape((n_groups * every,) + state["h"].shape[1:])
     if n_rem:
-        rem_p = jax.tree_util.tree_map(lambda a: a[n_groups * every:],
-                                       params["layers"])
-        x, h_rem = jax.lax.scan(mamba_step, x,
-                                (rem_p, state["h"][n_groups * every:]),
-                                unroll=n_rem if cfg.scan_unroll else 1)
+        x, h_rem = scan_layers(mamba_step, x,
+                               (rem_p, state["h"][n_groups * every:]),
+                               unroll=n_rem if cfg.scan_unroll else 1)
         h_all = jnp.concatenate([h_all, h_rem], axis=0)
     x = rms_norm(x, params["ln_f"])
     unemb = params["embed"].T if cfg.tie_embeddings else params["unembed"]

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..core.mapping import slice_tile_stacks, take
+
 # set by the launcher before tracing when cfg.moe_impl == "ep"
 MESH_FOR_EP = None
 
@@ -81,10 +83,10 @@ def _expert_matmul(p: Dict, name: str, xe, cfg, *, seed: int = 0):
         fn = jax.shard_map(shard_fn, mesh=mesh,
                            in_specs=(P("model"), P("model")),
                            out_specs=P("model"), check_vma=False)
-        return fn(pcl, xe).astype(xe.dtype)
+        return fn(slice_tile_stacks(pcl), xe).astype(xe.dtype)
     ys = []
     for e in range(cfg.n_experts):
-        pe = jax.tree_util.tree_map(lambda a: a[e], pcl)
+        pe = take(pcl, e)
         ys.append(nn_mod.packed_linear(pe, xe[e], ccfg, seed=seed + e))
     return jnp.stack(ys).astype(xe.dtype)
 

@@ -27,6 +27,7 @@ from ..core.types import CIMConfig, CoreSpec, NonIdealityConfig
 from ..core.quant import pact_quantize
 from ..core.noise import weight_noise
 from ..core import cim as cim_api
+from ..core.mapping import slice_tile_stacks, take
 from ..core.verify import verify_deployed
 
 
@@ -224,14 +225,15 @@ def sharded_packed_loop(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
     shapes share one kernel trace): 'row' shards read their input slice
     and their partial outputs fold left-to-right in shard order — the
     in-process analogue of the psum over 'model' — while 'col' shard
-    outputs concatenate in shard order. `sharded_packed_forward` is
-    bitwise-equal to this loop on a real mesh (tests/test_mesh_serving.py
-    holds the contract), so single-device serving and mesh serving cannot
-    drift.
+    outputs concatenate in shard order. A shard of a scanned layer stack
+    reads its tiles in place (`core.mapping.take` keeps the stack whole).
+    `sharded_packed_forward` is bitwise-equal to this loop on a real mesh
+    (tests/test_mesh_serving.py holds the contract), so single-device
+    serving and mesh serving cannot drift.
     """
     outs = []
     for s in range(spl.n_shards):
-        pcl = jax.tree_util.tree_map(lambda a: a[s], spl.shards)
+        pcl = take(spl.shards, s)
         xs = x
         if spl.partition == "row":
             r = x.shape[-1] // spl.n_shards
@@ -298,6 +300,11 @@ def sharded_packed_forward(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
         when 1-ulp nondeterminism vs the single-device oracle is
         acceptable.
 
+    Under shard_map each device gets its shard's own tiles: a plan that
+    reads a scanned stack in place is first cut to its layer's slice
+    (`core.mapping.slice_tile_stacks`), a copy of the layer's tiles that
+    the one-process loop does not make.
+
     Without a mesh (`serve --cim-mesh off`, the parity oracle) execution
     takes `sharded_packed_loop`, the single-device executor the shard_map
     path is bitwise-tested against. Replicated projections (n_shards == 1)
@@ -333,7 +340,7 @@ def sharded_packed_forward(spl: ShardedPackedLayer, x, ccfg: CIMConfig, *,
     fn = jax.shard_map(shard_fn, mesh=mesh,
                        in_specs=(P("model"), x_spec), out_specs=out_spec,
                        check_vma=False)
-    return fn(spl.shards, x)
+    return fn(slice_tile_stacks(spl.shards), x)
 
 
 def deploy_packed_stack(key, stacked_w: Dict[str, jax.Array],
@@ -355,9 +362,10 @@ def deploy_packed_stack(key, stacked_w: Dict[str, jax.Array],
     transformer layer): all of that layer's matrices go through the full
     plan -> schedule -> program -> calibrate -> pack pipeline ONCE. The
     resulting per-layer PackedCIMLayer pytrees are stacked back over L —
-    their static plan geometry is pytree aux data, so `lax.scan` slices
-    them without retracing and every projection stays a single Pallas
-    dispatch per step.
+    their static plan geometry is pytree aux data, so the layer scan
+    (`transformer.scan_layers`) runs them without retracing, every
+    projection a single Pallas dispatch per step that reads its layer's
+    tiles in place from the stack.
     """
     names = sorted(stacked_w)
     if isinstance(in_alpha, dict):
@@ -392,8 +400,9 @@ def _stack(trees, axis: int = 0):
 
 def packed_linear(pcl, x, ccfg: CIMConfig, *, seed: int = 0, mesh=None):
     """x: (B, n_in) float -> (B, n_out) float through one packed dispatch
-    (or one per shard). pcl: a (scan-sliced) core.cim.PackedCIMLayer or
-    ShardedPackedLayer. mesh: optional serving Mesh — multi-shard layers
+    (or one per shard). pcl: one layer's core.cim.PackedCIMLayer or
+    ShardedPackedLayer (its plans may read a layer stack in place,
+    `scan_layers`). mesh: optional serving Mesh — multi-shard layers
     then execute device-resident under shard_map (sharded_packed_forward);
     None keeps the unrolled single-process loop."""
     if isinstance(pcl, ShardedPackedLayer):

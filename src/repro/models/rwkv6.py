@@ -166,8 +166,9 @@ def forward(layers_p, x, cfg):
                              jnp.zeros((b, d), x.dtype), cfg)
         return x, None
 
-    x, _ = jax.lax.scan(body, x, layers_p,
-                        unroll=cfg.n_layers if cfg.scan_unroll else 1)
+    from .transformer import scan_layers
+    x, _ = scan_layers(body, x, layers_p,
+                       unroll=cfg.n_layers if cfg.scan_unroll else 1)
     return x
 
 
@@ -187,7 +188,7 @@ def init_state(cfg, batch, dtype):
 def prefill(params, state, tokens, cfg):
     """Chunked prefill: process a whole prompt, carrying per-layer state.
     Returns (last-position logits, filled state)."""
-    from .transformer import rms_norm, _softcap, constrain_batch
+    from .transformer import rms_norm, _softcap, constrain_batch, scan_layers
     x = params["embed"][tokens].astype(cfg.dtype)            # (B, T, d)
     b, t, d = x.shape
     h = d // HEAD
@@ -203,7 +204,7 @@ def prefill(params, state, tokens, cfg):
         x = x + y2
         return x, (S_T, x_tm_new, xn2[:, -1])
 
-    x, (S_new, x_tm_new, x_cm_new) = jax.lax.scan(
+    x, (S_new, x_tm_new, x_cm_new) = scan_layers(
         body, x, (params["layers"], state["S"], state["x_tm"],
                   state["x_cm"]),
         unroll=cfg.n_layers if cfg.scan_unroll else 1)
@@ -220,7 +221,7 @@ def decode_step(params, state, tokens, cfg):
     route through `cim_linear` like the chunked prefill path, so packed CIM
     serving covers decode with the SAME per-layer chips (one dispatch per
     projection per step)."""
-    from .transformer import rms_norm, _softcap, routed_linear
+    from .transformer import rms_norm, _softcap, routed_linear, scan_layers
     x = params["embed"][tokens[:, 0]].astype(cfg.dtype)      # (B, d)
     b, d = x.shape
     h = d // HEAD
@@ -250,7 +251,7 @@ def decode_step(params, state, tokens, cfg):
             * routed_linear(kk, p, "cv", cfg, seed=8)
         return x, (S_new, xn, xn2)
 
-    x, (S_new, x_tm_new, x_cm_new) = jax.lax.scan(
+    x, (S_new, x_tm_new, x_cm_new) = scan_layers(
         body, x, (params["layers"], state["S"], state["x_tm"], state["x_cm"]),
         unroll=cfg.n_layers if cfg.scan_unroll else 1)
     x = rms_norm(x, params["ln_f"])

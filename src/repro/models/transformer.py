@@ -8,8 +8,9 @@ One config-driven decoder (+optional encoder) covering:
   with shared attention (zamba2), encoder-decoder with cross-attention
   (seamless-m4t), and vision-prefix VLM (internvl2).
 
-Layers are scanned (jax.lax.scan over stacked params) with per-layer remat so
-the 80-layer/400B configs lower to compact HLO and bounded activation memory.
+Layers are scanned (`scan_layers`, a jax.lax.scan over stacked params whose
+packed CIM plans read their tiles in place) with per-layer remat so the
+80-layer/400B configs lower to compact HLO and bounded activation memory.
 Every linear can be routed through the NeuRRAM CIM path (cim_mode flag) — the
 paper's technique as a first-class feature (see cim_linear below).
 """
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..core.mapping import join_tile_stacks, split_tile_stacks
 from ..core.quant import pact_quantize
 from ..kernels.prng import hash_normal
 
@@ -125,7 +127,7 @@ def cim_linear(x, w, cfg: ArchConfig, *, seed: int = 0, packed=None):
              the bit-accurate oracle to first order while staying a single
              matmul (the full oracle lives in kernels/cim_mvm/ref.py).
     packed:  the real programmed chip datapath, served by the packed-tile
-             executor — `packed` is this projection's (scan-sliced)
+             executor — `packed` is this projection's (one layer's)
              ShardedPackedLayer (or bare PackedCIMLayer) from
              nn.deploy_transformer_cim; each TP shard's scheduled tile plan
              runs as ONE Pallas dispatch inside the serving jit. With
@@ -172,6 +174,19 @@ def routed_linear(x, p, name: str, cfg: ArchConfig, *, seed: int = 0):
     (dense blocks, rwkv6 mixes, mamba2 in/out projections)."""
     return cim_linear(x, p[name], cfg, seed=seed,
                       packed=p.get(name + "_cim"))
+
+
+def scan_layers(body, init, xs, **kw):
+    """`lax.scan(body, init, xs, **kw)` over a layer stack whose packed
+    plans read their tiles in place — the layer loop every model family
+    shares. Each plan's tile stack leaves the scanned operands
+    (core/mapping.split_tile_stacks) and is closed over whole, a loop
+    invariant; the scan slices only the plan's stack index, so a layer's
+    kernels start their DMAs inside the stack instead of on a copy of
+    the layer's tiles."""
+    xs, stacks = split_tile_stacks(xs)
+    return jax.lax.scan(lambda c, x: body(c, join_tile_stacks(x, stacks)),
+                        init, xs, **kw)
 
 
 # ------------------------------------------------------------------- layers
@@ -541,7 +556,7 @@ def _scan_blocks(params, x, cfg: ArchConfig, positions, memory=None):
     else:
         xs = (params["layers"], jnp.arange(cfg.n_layers))
     n_steps = (cfg.n_layers // 2) if interleaved else cfg.n_layers
-    x, _ = jax.lax.scan(body, x, xs, unroll=n_steps if cfg.scan_unroll else 1)
+    x, _ = scan_layers(body, x, xs, unroll=n_steps if cfg.scan_unroll else 1)
     return x
 
 
@@ -687,13 +702,13 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, memory=None):
         n = cfg.n_layers // 2
         ck = cache["k"].reshape((n, 2) + cache["k"].shape[1:])
         cv = cache["v"].reshape((n, 2) + cache["v"].shape[1:])
-        x, (nks, nvs) = jax.lax.scan(
+        x, (nks, nvs) = scan_layers(
             body, x, ((params["dense_layers"], params["layers"]), ck, cv,
                       jnp.arange(n)), unroll=n if cfg.scan_unroll else 1)
         nks = nks.reshape((cfg.n_layers,) + nks.shape[2:])
         nvs = nvs.reshape((cfg.n_layers,) + nvs.shape[2:])
     else:
-        x, (nks, nvs) = jax.lax.scan(
+        x, (nks, nvs) = scan_layers(
             body, x, (params["layers"], cache["k"], cache["v"],
                       jnp.arange(cfg.n_layers)),
             unroll=cfg.n_layers if cfg.scan_unroll else 1)

@@ -23,7 +23,13 @@ column-split) and per partition (col = wq, row = wo, none = the
   * deploy-time placement: multi-shard stacks are device-resident
     (not fully replicated) with the shard axis on 'model';
   * MoE expert dispatch: `_expert_matmul` under the mesh (expert-parallel
-    shard_map) is bitwise-equal to the unrolled expert loop.
+    shard_map) is bitwise-equal to the unrolled expert loop;
+  * whole steps: a 2-layer packed prefill + decode step under the mesh,
+    whose layer scan (`transformer.scan_layers`) hands each layer's plans
+    the whole tile stack and an index, which shard_map cuts back to the
+    layer's tiles (`core.mapping.slice_tile_stacks`), is bitwise-equal to
+    the same steps with the mesh off — dense tensor-parallel and MoE
+    expert-parallel.
 """
 import json
 
@@ -116,6 +122,36 @@ def check_moe(mesh, out):
                    .sharding.is_fully_replicated)}
 
 
+def check_steps(mesh, out):
+    for tag, cfg in (
+            ("dense", configs.get("gemma2-9b", smoke=True).replace(
+                dtype=jnp.float32, cim_mode="packed", n_layers=2,
+                d_ff=256)),
+            ("moe", configs.get("deepseek-moe-16b", smoke=True).replace(
+                dtype=jnp.float32, cim_mode="packed", n_layers=2))):
+        cfg_mesh = cfg.replace(cim_mesh=mesh)
+        params = nn.deploy_transformer_cim(
+            jax.random.PRNGKey(7), T.init_params(jax.random.PRNGKey(0), cfg),
+            cfg_mesh, mode="ideal")
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 9), 0,
+                                  cfg.vocab)
+        runs, n_shard_maps = [], 0
+        for c in (cfg, cfg_mesh):
+            prefill = jax.jit(lambda p, t, s, c=c: T.prefill(p, t, s, c))
+            decode = jax.jit(lambda p, s, t, c=c: T.decode_step(p, s, t, c))
+            lg_p, cache = prefill(params, toks[:, :-1],
+                                  T.init_cache(c, 2, 16, dtype=c.dtype))
+            lg_d, cache = decode(params, cache, toks[:, -1:])
+            runs.append([np.asarray(a) for a in
+                         jax.tree_util.tree_leaves((lg_p, lg_d, cache))])
+            n_shard_maps = str(jax.make_jaxpr(decode)(
+                params, cache, toks[:, -1:])).count("shard_map[")
+        out["step_" + tag] = {
+            "bitwise": all((a == b).all() for a, b in zip(*runs)),
+            "finite": bool(np.isfinite(runs[1][1]).all()),
+            "mesh_shard_maps": n_shard_maps}
+
+
 def main():
     out = {"device_count": jax.device_count()}
     mesh = serving_mesh()
@@ -132,6 +168,7 @@ def main():
                   CoreSpec(n_cores=4), mesh, out)
     check_variant("irdrop", base.replace(cim_ir_drop=2e-7), None, mesh, out)
     check_moe(mesh, out)
+    check_steps(mesh, out)
     print(json.dumps(out))
 
 

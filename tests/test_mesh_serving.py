@@ -170,7 +170,8 @@ def test_shard_map_parity_8_devices():
     """Bitwise parity of the shard_map executor against the unrolled-loop
     oracle on a real 8-device mesh — col/row/none partitions, multi-pass
     scheduled plans, IR-drop split plans, MoE expert-parallel dispatch,
-    one kernel trace per plan, deploy-time device placement."""
+    whole 2-layer prefill + decode steps reading scanned tile stacks, one
+    kernel trace per plan, deploy-time device placement."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(REPO / "src") + (
@@ -203,3 +204,7 @@ def test_shard_map_parity_8_devices():
                 # the lax.psum lowering works (close, not bitwise)
                 assert r["psum_close"], (tag, name, r)
     assert d["moe"]["bitwise"] and d["moe"]["placed"]
+    for tag in ("step_dense", "step_moe"):
+        # the mesh run's decode step runs its projections under shard_map
+        assert d[tag]["mesh_shard_maps"] > 0, (tag, d[tag])
+        assert d[tag]["bitwise"] and d[tag]["finite"], (tag, d[tag])

@@ -18,7 +18,10 @@ import repro.core as core
 from repro.core.types import CIMConfig, CoreSpec
 from repro.core.conductance import weights_to_conductances
 from repro.core.mapping import (MatrixReq, plan_layers, pack_tiles,
-                                multicore_mvm, multicore_mvm_packed)
+                                multicore_mvm, multicore_mvm_packed,
+                                join_tile_stacks, pack_tiles_transposed,
+                                schedule_tiles, slice_tile_stacks,
+                                split_tile_stacks, take)
 from repro.kernels.cim_mvm.ops import cim_mvm
 from repro.kernels.cim_mvm.kernel import TRACE_COUNTS
 
@@ -269,3 +272,78 @@ def test_engine_multi_layer_plan_shares_cores():
         corr = np.corrcoef(np.asarray(y).ravel(),
                            np.asarray(yt).ravel())[0, 1]
         assert corr > 0.95
+
+
+# ------------------------------------------------ tile stacks read in place
+
+def _plan_stack(kind, n_layers, n_shards):
+    """An (n_layers, n_shards) stack of `kind` plans, each over conductances
+    of its own, stacked the way deploy stacks a layer scan's plans.
+    Returns (stack, input width)."""
+    # a 200 x 500 matrix on 2 cores time-shares them: a multi-pass plan
+    reqs, spec, target = (([MatrixReq("m", 200, 500)], CoreSpec(n_cores=2),
+                           "m") if kind == "scheduled" else _plan_for("split"))
+    tiles = plan_layers(reqs, spec).tiles_for(target)
+    rows = max(t.row0 + t.rows for t in tiles)
+    cols = max(t.col0 + t.cols for t in tiles)
+    sched = schedule_tiles(tiles) if kind == "scheduled" else None
+    plans = []
+    for i in range(n_layers * n_shards):
+        _, _, cond, _ = _cim_setup(rows, cols, seed=10 + i)
+        gsum = cond.g_pos + cond.g_neg
+        plan = pack_tiles(tiles, cond.g_pos - cond.g_neg, gsum=gsum,
+                          v_decr=0.002, schedule=sched)
+        if kind == "transposed":
+            plan = pack_tiles_transposed(tiles, plan, gsum=gsum,
+                                         v_decr=0.002, schedule=sched)
+        plans.append(plan)
+    stack = jax.tree_util.tree_map(
+        lambda *a: jnp.stack(a).reshape((n_layers, n_shards) + a[0].shape),
+        *plans)
+    if kind == "scheduled":
+        assert stack.n_passes > 1
+    return stack, cols if kind == "transposed" else rows
+
+
+@pytest.mark.parametrize("kind", ["packed", "scheduled", "transposed"])
+@pytest.mark.parametrize("n_shards", [1, 2])
+@pytest.mark.parametrize("n_layers", [2, 3])
+def test_kernel_indexes_tile_stack_bitwise(n_layers, n_shards, kind):
+    """Each kernel reading an (L, n_shards, T, bk, bn) tile stack in place
+    at every (layer, shard) gives, bitwise, what it gives on that
+    position's sliced plan — and the positions differ, so the index picks
+    the tiles."""
+    stack, n_in = _plan_stack(kind, n_layers, n_shards)
+    cfg = CIMConfig(in_bits=4, out_bits=8)
+    x = jax.random.randint(jax.random.PRNGKey(5), (4, n_in), -7, 8)
+    tree, stacks = split_tile_stacks(stack)
+    assert tree.gd_tiles is None and stacks == [stack.gd_tiles]
+    bound = join_tile_stacks(tree, stacks)
+    outs = []
+    for li in range(n_layers):
+        layer = take(bound, li)
+        np.testing.assert_array_equal(slice_tile_stacks(layer).gd_tiles,
+                                      stack.gd_tiles[li])
+        for s in range(n_shards):
+            in_place = take(layer, s)
+            assert in_place.gd_tiles is stack.gd_tiles
+            assert int(in_place.stack_index) == li * n_shards + s
+            sliced = jax.tree_util.tree_map(lambda a: a[li, s], stack)
+            y = np.asarray(multicore_mvm_packed(x, in_place, cfg))
+            np.testing.assert_array_equal(
+                y, np.asarray(multicore_mvm_packed(x, sliced, cfg)))
+            outs.append(y.tobytes())
+    assert len(set(outs)) == n_layers * n_shards
+
+
+def test_stacked_plan_needs_one_position():
+    """A plan spanning several stack positions, or a stack with no index,
+    is refused instead of silently running position 0."""
+    stack, n_in = _plan_stack("packed", 2, 1)
+    cfg = CIMConfig(in_bits=4, out_bits=8)
+    x = jnp.zeros((4, n_in))
+    with pytest.raises(ValueError, match="index one layer"):
+        multicore_mvm_packed(x, stack, cfg)
+    bound = join_tile_stacks(*split_tile_stacks(stack))
+    with pytest.raises(ValueError, match="still spans"):
+        multicore_mvm_packed(x, take(bound, 0), cfg)

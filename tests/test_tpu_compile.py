@@ -8,17 +8,26 @@ cast was one such case. Shapes are codeqwen1.5-7b's: wq (4096 x 4096,
 512 tiles of 128x256) and w_o (13440 x 4096, 1680 tiles) at 8 decode
 rows. The topology is described inside a fixture, so every test worker
 collects the same tests and only the worker running this file loads the
-TPU compiler.
+TPU compiler. The whole pool decode step is compiled too, at codeqwen's
+and rwkv6's published widths with 2 layers, to show that its kernels read
+each layer's tiles in place from the scanned stack.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.core import mapping
 from repro.core.types import CIMConfig, CoreSpec
+from repro.kernels.cim_mvm import ops
 from repro.kernels.cim_mvm.ops import packed_call
 from repro.kernels.noisy_matmul.kernel import noisy_matmul_pallas
+from repro.launch.scheduler import count_packed_dispatches, init_pool
+from repro.launch.steps import arch_serving, make_pool_decode_step
+from repro.obs import MetricsRegistry
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +124,44 @@ def test_noisy_matmul_compiles(one_chip):
     text = _compiled_text(
         lambda *a: noisy_matmul_pallas(*a, interpret=False), x, w, s, seed)
     assert "tpu_custom_call" in text
+
+
+# an instruction whose value is an array of 128 x 256 tiles and which is
+# neither an operand of the step, a view of one, nor a tuple element: a
+# copy (or slice) of tiles made before a kernel reads them
+TILE_COPY = re.compile(r"= f32\[[\d,]*,128,256\]\S* "
+                       r"(?!parameter\(|bitcast\(|get-tuple-element\()"
+                       r"[\w-]+\(")
+
+
+@pytest.mark.parametrize("arch,widths,n_dispatch", [
+    ("codeqwen1.5-7b", dict(vocab=92416, n_kv_heads=4, d_head=128,
+                            qkv_bias=True, rope_theta=1e6), 14),
+    ("rwkv6-7b", {}, 16)])
+def test_pool_decode_reads_tile_stacks_in_place(one_chip, monkeypatch, arch,
+                                                widths, n_dispatch):
+    """The compiled pool decode step (32 slots of 640 tokens, 2 layers at
+    published widths) holds no dynamic-slice or copy of a tile stack:
+    every packed dispatch, 7 or 8 projections x 2 layers, indexes the
+    scanned stack in place, as the engine's gauge reports."""
+    cfg = configs.get(arch).replace(n_layers=2, cim_mode="packed",
+                                    dtype=jnp.float32, **widths)
+    sv = arch_serving(cfg)
+    params = jax.eval_shape(lambda: sv.deploy_cim(
+        jax.random.PRNGKey(1), sv.init_params(jax.random.PRNGKey(0)),
+        mode="ideal", spec=CoreSpec(rows=256, cols=256, n_cores=8192)))
+    pool = jax.eval_shape(lambda: init_pool(cfg, 32, 640))
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    gauge = MetricsRegistry().gauge("serve_packed_dispatches")
+    step = count_packed_dispatches(make_pool_decode_step(cfg), gauge,
+                                   "pool_decode")
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        _on(one_chip, params), _on(one_chip, pool)).compile().as_text()
+    assert gauge.value(entry="pool_decode", tile_read="in_place") \
+        == n_dispatch
+    assert gauge.value(entry="pool_decode", tile_read="sliced") == 0
+    kernels = re.findall(r"%(cim_mvm_packed_pallas\.\d+) = ", text)
+    assert len(kernels) == n_dispatch // 2       # one scan body, 2 layers
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if TILE_COPY.search(line)]
+    assert not copies, copies

@@ -17,9 +17,11 @@ actually shipped or reviewed out:
   R003 host-op-in-traced        no `np.` calls or Python `if` on a traced
                                 parameter inside a function handed to
                                 `jax.jit` / `shard_map` / `pallas_call`
-                                (host ops silently constant-fold at trace
-                                time; tracer `if` raises only on the
-                                branch actually taken).
+                                or a layer scan (`jax.lax.scan`,
+                                `scan_layers`) (host ops silently
+                                constant-fold at trace time; tracer `if`
+                                raises only on the branch actually
+                                taken).
   R004 static-argnames-real     `static_argnames` must name real
                                 parameters and `static_argnums` must be in
                                 range — jax only validates lazily at call
@@ -78,7 +80,10 @@ JIT_NAMES = {"jax.jit", "jit", "pjit", "jax.pjit", "api.jit"}
 TRACE_WRAPPERS = JIT_NAMES | {
     "shard_map", "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "pl.pallas_call", "pallas_call", "jax.checkpoint", "jax.remat",
-    "jax.vmap", "vmap", "jax.lax.scan"}
+    "jax.vmap", "vmap", "jax.lax.scan",
+    # models/transformer.scan_layers: the layer scan every model family
+    # shares hands its body to jax.lax.scan inside a lambda
+    "scan_layers", "T.scan_layers"}
 PARTIAL_NAMES = {"functools.partial", "partial"}
 # attributes of a traced value that are static python data at trace time
 STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "sharding", "at"}
