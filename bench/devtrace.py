@@ -3,16 +3,20 @@
 `capture()` records the JAX profiler's trace (python tracer off) into a
 temporary directory and `load()` keeps only what the readers need:
 
-  device: {plane: [(op name, start_ns, duration_ns), ...]} from each TPU
-          plane's "XLA Ops" line (one event per executed HLO op or kernel);
-  host:   [(span name, start_ns, duration_ns), ...] of the benchmark's own
-          TraceAnnotation spans around the engine's calls.
+  device:  {plane: [(op name, start_ns, duration_ns), ...]} from each TPU
+           plane's "XLA Ops" line (one event per executed HLO op or kernel);
+  modules: {plane: [(module name, start_ns, duration_ns), ...]} from each
+           TPU plane's "XLA Modules" line (one event per program run);
+  program: [(span name, start_ns, duration_ns), ...] of the program's own
+           `serve.*` spans (the engine loop's phases, `repro.obs.trace`);
+  host:    the same of the benchmark's own spans (the measured window).
 
 The reduction works on those plain lists, so it can be checked on a small
 recorded trace (tests/bench/data).
 """
 from __future__ import annotations
 
+import bisect
 import contextlib
 import glob
 import os
@@ -26,8 +30,11 @@ Event = Tuple[str, float, float]
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
+PROGRAM_PREFIX = "serve."
+NO_SPAN = "host: no program span"
 
 
 @contextlib.contextmanager
@@ -57,7 +64,9 @@ def load(log_dir: str) -> dict:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     device: Dict[str, List[Event]] = {}
+    modules: Dict[str, List[Event]] = {}
     host: List[Event] = []
+    program: List[Event] = []
     lines_seen: Dict[str, List[str]] = {}
     for path in paths:
         pd = ProfileData.from_file(path)
@@ -69,15 +78,23 @@ def load(log_dir: str) -> dict:
                         device.setdefault(plane.name, []).extend(
                             (op_name(e.name), e.start_ns, e.duration_ns)
                             for e in ln.events)
+                    elif ln.name == MODULES_LINE:
+                        modules.setdefault(plane.name, []).extend(
+                            (e.name, e.start_ns, e.duration_ns)
+                            for e in ln.events)
             elif plane.name == HOST_PLANE:
                 for ln in plane.lines:
-                    host.extend((e.name, e.start_ns, e.duration_ns)
-                                for e in ln.events
-                                if e.name.startswith(SPAN_PREFIX))
-    for evs in device.values():
+                    for e in ln.events:
+                        if e.name.startswith(SPAN_PREFIX):
+                            host.append((e.name, e.start_ns, e.duration_ns))
+                        elif e.name.startswith(PROGRAM_PREFIX):
+                            program.append((e.name, e.start_ns,
+                                            e.duration_ns))
+    for evs in list(device.values()) + list(modules.values()) \
+            + [host, program]:
         evs.sort(key=lambda e: e[1])
-    host.sort(key=lambda e: e[1])
-    return {"device": device, "host": host, "lines": lines_seen}
+    return {"device": device, "modules": modules, "host": host,
+            "program": program, "lines": lines_seen}
 
 
 # ------------------------------------------------------------ reduction
@@ -129,32 +146,71 @@ def top_ops(events: Sequence[Event], k: int = 10) -> List[List]:
     return [[n, d * 1e-9] for n, d in best]
 
 
-def idle_gaps(events: Sequence[Event], host: Sequence[Event],
-              window: Tuple[float, float], k: int = 10) -> List[List]:
-    """The k longest idle gaps of the device inside `window`, each named by
-    the engine call (host span) that covered most of it, or "host: between
-    engine calls" where none did, in seconds."""
-    busy = union((s, s + d) for _, s, d in events)
+def idle_intervals(events: Sequence[Event], window: Tuple[float, float]
+                   ) -> List[Tuple[float, float]]:
+    """[start, end) of each stretch of the window in which no op ran."""
     w0, w1 = window
-    gaps = []
-    prev = w0
-    for s, e in busy:
+    out, prev = [], w0
+    for s, e in union((max(s, w0), min(s + d, w1)) for _, s, d in events
+                      if s + d > w0 and s < w1) + [(w1, w1)]:
         if s > prev:
-            gaps.append((max(prev, w0), min(s, w1)))
+            out.append((prev, s))
         prev = max(prev, e)
-    if prev < w1:
-        gaps.append((prev, w1))
-    gaps = sorted((g for g in gaps if g[1] > g[0]),
+    return out
+
+
+def innermost(spans: Sequence[Event]) -> List[Tuple[float, float, str]]:
+    """[(start, end, name)] cutting the spans' time into pieces, each
+    named by the innermost span open over it (spans of one thread nest)."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, str]] = []       # (end, name) of open spans
+    cursor = 0.0
+
+    def close_until(t: float) -> None:
+        nonlocal cursor
+        while stack and stack[-1][0] <= t:
+            end, name = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, name))
+                cursor = end
+
+    for n, s, d in sorted(spans, key=lambda e: (e[1], -e[2])):
+        close_until(s)
+        if stack and s > cursor:
+            out.append((cursor, s, stack[-1][1]))
+        # a child never outlives its parent
+        e = min(s + d, stack[-1][0]) if stack else s + d
+        stack.append((e, n))
+        cursor = s
+    close_until(float("inf"))
+    return out
+
+
+def idle_gaps(events: Sequence[Event], program: Sequence[Event],
+              window: Tuple[float, float], offset: float = 0.0,
+              k: int = 10) -> List[List]:
+    """The k longest idle gaps of the device inside `window`, in seconds,
+    each named by the innermost program span that covered most of it, or
+    NO_SPAN where none did. `offset` moves device times onto the program
+    spans' clock (bench/phases.align)."""
+    gaps = sorted(idle_intervals(events, window),
                   key=lambda g: g[0] - g[1])[:k]
+    pieces = innermost(program)
+    starts = [p[0] for p in pieces]
     out = []
     for g0, g1 in gaps:
-        best, cover = "host: between engine calls", 0.0
-        for n, s, d in host:
-            if n == WINDOW_SPAN:
-                continue
-            c = min(g1, s + d) - max(g0, s)
-            if c > cover:
-                best, cover = n, c
+        h0, h1 = g0 + offset, g1 + offset
+        cover: Dict[str, float] = {}
+        # pieces are disjoint and sorted: start one before the first that
+        # begins inside the gap
+        for ps, pe, name in pieces[max(bisect.bisect_left(starts, h0) - 1,
+                                       0):]:
+            if ps >= h1:
+                break
+            c = min(h1, pe) - max(h0, ps)
+            if c > 0:
+                cover[name] = cover.get(name, 0.0) + c
+        best = max(cover, key=cover.get) if cover else NO_SPAN
         out.append([best, (g1 - g0) * 1e-9])
     return out
 

@@ -6,6 +6,9 @@ Everything that belongs to one configuration, traffic mix, cell check or
 metric is a file found by name:
 
   bench/configs/<config>.json   sizes, CIM operating point, source, cuts
+  bench/families/<family>.py    a configuration's `family`: arch_kw,
+                                weights, layers (the reference's), the
+                                packed calls of a step, model FLOPs
   bench/mixes/<mix>.json        traffic parameters (bench/traffic.py)
   bench/checks/<cell>.json      limits of the numbers `correct` compares
   bench/metrics/<metric>.py     read(ctx) -> float | None, one per metric
@@ -19,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import sys
@@ -27,6 +29,8 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+
+from .families import family, load
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -77,12 +81,8 @@ def peaks(device_kind: str) -> dict:
 
 
 def reader(name: str) -> Callable:
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load(os.path.join(BENCH_DIR, "metrics", name + ".py"),
+                f"bench_metric_{name}").read
 
 
 @dataclasses.dataclass
@@ -103,24 +103,15 @@ class Context:
 
 def arch_config(config: dict, mesh):
     """The program's ArchConfig for a configuration file (sizes from the
-    file, family flags from the program's registry)."""
+    file through its family's `arch_kw`, family flags from the program's
+    registry)."""
     import jax.numpy as jnp
     from repro import configs
-    c, cim = config, config["cim"]
-    base = configs.get(config["arch"])
-    kw = dict(n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-              d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-              tie_embeddings=c["tie_word_embeddings"])
-    if c["family"] == "transformer":
-        kw.update(n_heads=c["num_attention_heads"],
-                  n_kv_heads=c["num_key_value_heads"], d_head=c["head_dim"],
-                  qkv_bias=c["qkv_bias"], rope_theta=c["rope_theta"])
-    else:
-        h = c["attention_hidden_size"] // c["head_size"]
-        kw.update(n_heads=h, n_kv_heads=h)
-    return base.replace(cim_mode="packed", dtype=jnp.float32,
-                        cim_in_bits=cim["in_bits"],
-                        cim_out_bits=cim["out_bits"], cim_mesh=mesh, **kw)
+    cim = config["cim"]
+    return configs.get(config["arch"]).replace(
+        cim_mode="packed", dtype=jnp.float32, cim_in_bits=cim["in_bits"],
+        cim_out_bits=cim["out_bits"], cim_mesh=mesh,
+        **family(config).arch_kw(config))
 
 
 def _check_layout(made, expect) -> None:
@@ -130,21 +121,6 @@ def _check_layout(made, expect) -> None:
     if got != want:
         raise ValueError("benchmark weights do not match the program's "
                          f"parameter layout:\n{got}\nvs\n{want}")
-
-
-def _annotate(engine) -> None:
-    """Host spans around the engine's calls, in the profiler's trace."""
-    import jax
-    for meth, span in (("_admit", "bench.engine.admit"),
-                       ("_prefill_one_chunk", "bench.engine.prefill_chunk"),
-                       ("_decode_once", "bench.engine.decode_step"),
-                       ("_finish", "bench.engine.finish")):
-        fn = getattr(engine, meth)
-
-        def wrapped(*a, _fn=fn, _span=span, **k):
-            with jax.profiler.TraceAnnotation(_span):
-                return _fn(*a, **k)
-        setattr(engine, meth, wrapped)
 
 
 def _sample(requests, check: dict, seed: int) -> list:
@@ -238,7 +214,6 @@ def serve_window(config: dict, mix: dict, planned, params, cfg, mesh, *,
     trace = None
     if traced:
         import jax
-        _annotate(engine)
         with devtrace.capture() as trace:
             with jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN):
                 stats = engine.run(requests, warm=False)
@@ -288,7 +263,7 @@ def _run(cell_name, seed, seconds, traced, t_start, hook, config, mix, check,
          metric_list) -> dict:
     import jax
     from repro.launch.mesh import serving_mesh
-    from . import devtrace, traffic, weights
+    from . import devtrace, phases, traffic, weights
     wkey, dkey = keys(seed)
     planned = traffic.generate(mix, seed, seconds, config["vocab_size"])
     mesh = serving_mesh()
@@ -315,16 +290,21 @@ def _run(cell_name, seed, seconds, traced, t_start, hook, config, mix, check,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     out: Dict[str, Any] = {"attempted": len(requests)}
     if trace is not None:
-        print(f"bench: trace lines {trace['lines']}, {len(trace['host'])} "
-              "host spans", file=sys.stderr, flush=True)
+        print(f"bench: trace lines {trace['lines']}, "
+              f"{len(trace['program'])} program spans", file=sys.stderr,
+              flush=True)
     if trace is not None and trace.get("window"):
         w = trace["window"]
-        evs = devtrace.in_window(devtrace.device_events(trace), w)
+        dev_evs = devtrace.device_events(trace)
+        evs = devtrace.in_window(dev_evs, w)
         device["busy_s"] = devtrace.busy_ns(evs) * 1e-9
         device["window_s"] = (w[1] - w[0]) * 1e-9
+        found = phases.align(trace["program"], phases.step_runs(
+            phases.device_modules(trace), dev_evs))
         out["breakdown"] = {
             "device_ops": devtrace.top_ops(evs),
-            "idle_gaps": devtrace.idle_gaps(evs, trace["host"], w)}
+            "idle_gaps": devtrace.idle_gaps(evs, trace["program"], w,
+                                            found[0] if found else 0.0)}
     replay = [(r.prompt, list(r.tokens))
               for r in _sample(requests, mix["check"], seed)]
     failed = sum(1 for r in requests

@@ -5,6 +5,7 @@ call, from bench/flops), over the summed device time of the kernel's events
 in the trace. Reads nothing where the events do not match the calls the
 engine's counters say ran."""
 from bench import devtrace, flops
+from bench.families import family
 
 # the packed CIM kernel's ops in the device trace (instruction names
 # cim_mvm_packed_pallas.<n> on a TPU v5e)
@@ -21,9 +22,8 @@ def read(ctx):
     c = ctx.mix["chunk"]
     chunks = [min(c, len(r.prompt) - s) for r in ctx.requests
               for s in range(0, len(r.prompt), c)]
-    per_dispatch = len(flops.projections(ctx.config)) \
-        * ctx.config["num_hidden_layers"]
-    if not n_events or n_events != (steps + len(chunks)) * per_dispatch:
+    per_step = len(family(ctx.config).dispatches(ctx.config))
+    if not n_events or n_events != (steps + len(chunks)) * per_step:
         return None
     least = sum(max(ops / ctx.peaks["flops_per_s"],
                     moved / ctx.peaks["hbm_bytes_per_s"])

@@ -2,9 +2,9 @@
 device while the innermost program span was a host phase (any `serve.`
 span but `*.wait` and `serve.sleep`), in the same points as
 device_idle_pct. Reads the program spans and program runs that
-bench/phases.load() keeps beside devtrace.load()'s keys, and nothing where
-the trace lacks them or no single clock offset places every packed step's
-run inside its dispatch-to-wait interval."""
+devtrace.load() keeps, and nothing where the trace lacks them or no single
+clock offset places every packed step's run inside its dispatch-to-wait
+interval."""
 import sys
 
 from bench import devtrace, phases

@@ -1,14 +1,11 @@
-"""The program's own engine-loop phase spans in a profiler trace: loading
-them, aligning their clock with the device's, and naming the device's idle
-time by what the host was doing.
+"""The program's own engine-loop phase spans in a profiler trace: aligning
+their clock with the device's, and naming the device's idle time by what
+the host was doing.
 
 The engine records every loop phase as a `serve.*` span
-(`repro.obs.trace.Tracer`), which the profiler keeps on its host plane.
-`load()` is `devtrace.load()` plus
-
-  program: [(span name, start_ns, duration_ns), ...] of those spans;
-  modules: {plane: [(module name, start_ns, duration_ns), ...]} from each
-           TPU plane's "XLA Modules" line (one event per program run).
+(`repro.obs.trace.Tracer`), which the profiler keeps on its host plane;
+`devtrace.load()` returns them as `program`, and each TPU plane's program
+runs as `modules`.
 
 The profiler converts device events to the host's clock only roughly: on
 a TPU v5e they read one to two milliseconds early. `align()` measures the
@@ -21,16 +18,12 @@ between its dispatch's start and the end of the wait that follows it.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from bench import devtrace
 from bench.devtrace import Event
 
-PROGRAM_PREFIX = "serve."
-MODULES_LINE = "XLA Modules"
 # a packed step's dispatch span -> the span that waits for its result
 STEP_WAIT = {"serve.dispatch.pool_decode": "serve.decode.wait",
              "serve.dispatch.slot_prefill": "serve.prefill.wait"}
@@ -38,37 +31,6 @@ SLEEP = "serve.sleep"
 # the packed CIM kernel's events (instruction names on a TPU v5e)
 KERNEL = r"^cim_mvm_packed_pallas"
 UNATTRIBUTED = ""            # idle time under no program span
-
-
-def trace_events(log_dir: str) -> dict:
-    """{"program": the host plane's `serve.*` spans, "modules": each TPU
-    plane's program runs}, by start."""
-    from jax.profiler import ProfileData
-    program: List[Event] = []
-    modules: Dict[str, List[Event]] = {}
-    for path in glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                          recursive=True):
-        for plane in ProfileData.from_file(path).planes:
-            if plane.name.startswith(devtrace.DEVICE_PREFIX):
-                for ln in plane.lines:
-                    if ln.name == MODULES_LINE:
-                        modules.setdefault(plane.name, []).extend(
-                            (e.name, e.start_ns, e.duration_ns)
-                            for e in ln.events)
-            elif plane.name == devtrace.HOST_PLANE:
-                for ln in plane.lines:
-                    program.extend((e.name, e.start_ns, e.duration_ns)
-                                   for e in ln.events
-                                   if e.name.startswith(PROGRAM_PREFIX))
-    program.sort(key=lambda e: e[1])
-    for evs in modules.values():
-        evs.sort(key=lambda e: e[1])
-    return {"program": program, "modules": modules}
-
-
-def load(log_dir: str) -> dict:
-    """devtrace.load()'s keys, `program` and `modules`."""
-    return dict(devtrace.load(log_dir), **trace_events(log_dir))
 
 
 def device_modules(trace: dict) -> List[Event]:
@@ -126,49 +88,15 @@ def align(program: Sequence[Event], runs: Sequence[Tuple[float, float]]
     return (lo + hi) / 2, hi - lo
 
 
-def innermost(program: Sequence[Event]
-              ) -> List[Tuple[float, float, str]]:
-    """[(start, end, name)] cutting the spans' time into pieces, each
-    named by the innermost span open over it (spans of one thread nest)."""
-    out: List[Tuple[float, float, str]] = []
-    stack: List[Tuple[float, str]] = []       # (end, name) of open spans
-    cursor = 0.0
-
-    def close_until(t: float) -> None:
-        nonlocal cursor
-        while stack and stack[-1][0] <= t:
-            end, name = stack.pop()
-            if end > cursor:
-                out.append((cursor, end, name))
-                cursor = end
-
-    for n, s, d in sorted(program, key=lambda e: (e[1], -e[2])):
-        close_until(s)
-        if stack and s > cursor:
-            out.append((cursor, s, stack[-1][1]))
-        # a child never outlives its parent
-        e = min(s + d, stack[-1][0]) if stack else s + d
-        stack.append((e, n))
-        cursor = s
-    close_until(float("inf"))
-    return out
-
-
 def idle_by_phase(device: Sequence[Event], program: Sequence[Event],
                   window: Tuple[float, float], offset: float
                   ) -> Dict[str, float]:
     """Nanoseconds of the window in which no op ran on the device, by the
     innermost program span open then (UNATTRIBUTED where none was), with
     device times moved onto the program's clock by `offset`."""
-    w0, w1 = window
-    busy = devtrace.union((s, s + d) for _, s, d in
-                          devtrace.in_window(device, window))
-    gaps, prev = [], w0
-    for s, e in busy + [(w1, w1)]:
-        if s > prev:
-            gaps.append((prev + offset, s + offset))
-        prev = max(prev, e)
-    pieces = innermost(program)
+    gaps = [(g0 + offset, g1 + offset)
+            for g0, g1 in devtrace.idle_intervals(device, window)]
+    pieces = devtrace.innermost(program)
     out: Dict[str, float] = {}
     j = 0
     for g0, g1 in gaps:

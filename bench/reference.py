@@ -17,9 +17,10 @@ Semantics (NeuRRAM voltage-mode MVM, one core = one tile):
   * counts are de-normalized (count norm v_decr), summed over a column's row
     tiles, and scaled by w_max (alpha / n) / (v_read g_max).
 
-The float parts follow the configuration: embedding, RMSNorm, rotary
-embedding, causal softmax attention, SwiGLU, the RWKV-6 recurrence as a
-plain sequential scan over tokens, and the LM head.
+Each model family's layers are written in its own file,
+bench/families/<family>.py (`layers`), from the parts here: the CIM
+tiles (`Chip`), `contract`, `rms`, `rope` and causal softmax `attention`.
+This file adds the embedding, the final norm and the LM head.
 
 `precision` is "highest" (float32, the configuration's statement) or "high":
 every contraction as three bfloat16 products (hi*hi + hi*lo + lo*hi), the
@@ -29,11 +30,13 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .families import family
 
 _HI = jax.lax.Precision.HIGHEST
 ROW_BLOCK = 128          # rows per CIM application block (bounds memory)
@@ -139,27 +142,40 @@ def _apply(x, t, *, cim_items, alpha, n, precision):
     return acc * t["w_max"] * scale / (cim["v_read"] * cim["g_max_uS"])
 
 
-def calibration_batch(deploy_key, layer: int, index: int, k: int,
-                      alpha: float, batch: int):
-    """The calibration rule of the configuration files."""
-    kl = jax.random.fold_in(jax.random.fold_in(deploy_key, 1), layer)
-    _, k_syn = jax.random.split(jax.random.fold_in(kl, index))
+def scalars(c: dict) -> tuple:
+    """A dict's top-level numbers, as a static jit argument."""
+    return tuple(sorted((k, v) for k, v in c.items()
+                        if isinstance(v, (int, float, bool))))
+
+
+def chip_key(deploy_key, layer: int):
+    """The key of one layer's chip under the configurations'
+    `calibration_rule`: fold_in(fold_in(deploy_key, 1), layer)."""
+    return jax.random.fold_in(jax.random.fold_in(deploy_key, 1), layer)
+
+
+def calibration_batch(key, index: int, k: int, alpha: float, batch: int):
+    """The calibration inputs of a chip's `index`-th projection (in sorted
+    name order), from the chip's key."""
+    _, k_syn = jax.random.split(jax.random.fold_in(key, index))
     return alpha * jax.random.truncated_normal(k_syn, -2.0, 2.0, (batch, k))
 
 
 class Chip:
-    """Tables of one layer's projections, built on first use."""
+    """Tables of one chip's projections, built on first use.
 
-    def __init__(self, config: dict, layer_w: Dict, layer: int, deploy_key,
-                 precision: str):
+    weights: name -> (K, N) matrix; key: the chip's key (`chip_key` for a
+    layer's chip); names: the projections the chip holds, which fix each
+    one's calibration index (default: the configuration's projections)."""
+
+    def __init__(self, config: dict, weights: Dict, key, precision: str,
+                 names: Sequence[str] = ()):
         self.cim = config["cim"]
-        self.items = tuple(sorted((k, v) for k, v in self.cim.items()
-                                  if isinstance(v, (int, float, bool))))
-        self.w = layer_w
-        self.layer = layer
-        self.key = deploy_key
+        self.items = scalars(self.cim)
+        self.w = weights
+        self.key = key
         self.precision = precision
-        self.names = sorted(self.cim["projections"])
+        self.names = sorted(names or self.cim["projections"])
         self._tables: Dict[str, dict] = {}
 
     def alpha(self, name: str) -> float:
@@ -169,9 +185,8 @@ class Chip:
     def __call__(self, name: str, x):
         w = self.w[name]
         if name not in self._tables:
-            xc = calibration_batch(self.key, self.layer,
-                                   self.names.index(name), w.shape[0],
-                                   self.alpha(name),
+            xc = calibration_batch(self.key, self.names.index(name),
+                                   w.shape[0], self.alpha(name),
                                    self.cim["calibration_batch"])
             self._tables[name] = _table(w, xc, cim_items=self.items,
                                         alpha=self.alpha(name),
@@ -181,14 +196,14 @@ class Chip:
                       precision=self.precision)
 
 
-# ------------------------------------------------------------- models
+# ------------------------------------------------------------- layers
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x (S, H, D), rotate-half layout, positions 0..S-1."""
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
@@ -199,10 +214,10 @@ def _rope(x, theta):
 
 
 @functools.partial(jax.jit, static_argnames=("c_items", "precision"))
-def _attention(q, k, v, *, c_items, precision):
+def attention(q, k, v, *, c_items, precision):
     """Causal softmax attention of one sequence, q/k/v (S, H, D)."""
     c = dict(c_items)
-    q, k = _rope(q, c["rope_theta"]), _rope(k, c["rope_theta"])
+    q, k = rope(q, c["rope_theta"]), rope(k, c["rope_theta"])
     rep = q.shape[1] // k.shape[1]
     k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
     s = contract("qhd,khd->hqk", q, k, precision) / math.sqrt(q.shape[-1])
@@ -211,79 +226,15 @@ def _attention(q, k, v, *, c_items, precision):
     return contract("hqk,khd->qhd", p, v, precision)
 
 
-@functools.partial(jax.jit, static_argnames=("precision",))
-def _wkv(r, k, v, w, u, *, precision):
-    """RWKV-6 recurrence, one sequence, as a sequential scan over tokens:
-    o_t = r_t (S + diag(u) k_t v_t^T), S <- diag(w_t) S + k_t v_t^T."""
-    def step(S, inp):
-        rt, kt, vt, wt = inp                                # (H, N)
-        kv = kt[:, :, None] * vt[:, None, :]
-        o = contract("hn,hnm->hm", rt, S + u[:, :, None] * kv, precision)
-        return S * wt[:, :, None] + kv, o
-
-    h, n = r.shape[1], r.shape[2]
-    _, o = jax.lax.scan(step, jnp.zeros((h, n, n), jnp.float32),
-                        (r, k, v, w))
-    return o
-
-
-def _transformer_layer(c, p, chip, x, precision):
-    """x (B, S, d) padded sequences."""
-    b, s, d = x.shape
-    nh, nkv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                   c["head_dim"])
-    eps = c["rms_norm_eps"]
-    flat = lambda a: a.reshape(b * s, -1)
-    h = flat(_rms(x, p["ln1"], eps))
-    q, k, v = chip("wq", h), chip("wk", h), chip("wv", h)
-    if c["qkv_bias"]:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(b, s, nh, hd)
-    k = k.reshape(b, s, nkv, hd)
-    v = v.reshape(b, s, nkv, hd)
-    items = tuple(sorted((kk, vv) for kk, vv in c.items()
-                         if isinstance(vv, (int, float, bool))))
-    attn = jnp.stack([_attention(q[i], k[i], v[i], c_items=items,
-                                 precision=precision) for i in range(b)])
-    x = x + chip("wo", attn.reshape(b * s, nh * hd)).reshape(b, s, d)
-    h2 = flat(_rms(x, p["ln2"], eps))
-    g = jax.nn.silu(chip("w_g", h2)) * chip("w_i", h2)
-    return x + chip("w_o", g).reshape(b, s, d)
-
-
-def _shift(x):
-    return jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
-
-
-def _rwkv6_layer(c, p, chip, x, precision):
-    b, s, d = x.shape
-    n = c["head_size"]
-    h = c["attention_hidden_size"] // n
-    eps = c["rms_norm_eps"]
-    flat = lambda a: a.reshape(b * s, -1)
-    xn = _rms(x, p["ln1"], eps)
-    xs = _shift(xn)
-    mix = lambda i: flat(xn + (xs - xn) * p["mu"][i])
-    r, k, v = chip("wr", mix(0)), chip("wk", mix(1)), chip("wv", mix(2))
-    lora = contract("sr,rd->sd", jnp.tanh(contract(
-        "sd,dr->sr", mix(3), p["w_lora_a"], precision)), p["w_lora_b"],
-        precision)
-    w = jnp.exp(-jnp.exp(p["w_base"] + lora))
-    g = jax.nn.silu(chip("wg", mix(4)))
-    hv = lambda a: a.reshape(b, s, h, n)
-    o = jnp.stack([_wkv(hv(r)[i], hv(k)[i], hv(v)[i], hv(w)[i], p["u"],
-                        precision=precision) for i in range(b)])
-    x = x + chip("wo", o.reshape(b * s, d) * g).reshape(b, s, d)
-    xn2 = _rms(x, p["ln2"], eps)
-    xs2 = _shift(xn2)
-    xk = flat(xn2 + (xs2 - xn2) * p["cmu"][0])
-    xr = flat(xn2 + (xs2 - xn2) * p["cmu"][1])
-    kk = jnp.square(jax.nn.relu(chip("ck", xk)))
-    return x + (jax.nn.sigmoid(chip("cr", xr)) * chip("cv", kk)
-                ).reshape(b, s, d)
-
-
-_LAYERS = {"transformer": _transformer_layer, "rwkv6": _rwkv6_layer}
+def each_layer(config: dict, params: Dict, x, deploy_key, precision: str,
+               layer: Callable):
+    """x through one uniform stack params["layers"], each layer on its own
+    chip: x = layer(config, p, chip, x, precision)."""
+    for li in range(config["num_hidden_layers"]):
+        p = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+        x = layer(config, p, Chip(config, p, chip_key(deploy_key, li),
+                                  precision), x, precision)
+    return x
 
 
 def logits(config: dict, params: Dict, deploy_key,
@@ -303,15 +254,10 @@ def logits(config: dict, params: Dict, deploy_key,
     for i, t in enumerate(seqs):
         toks[i, :len(t)] = t
     x = params["embed"][jnp.asarray(toks)]
-    layer_fn = _LAYERS[config["family"]]
-    for li in range(config["num_hidden_layers"]):
-        p = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        chip = Chip(config, p, li, deploy_key, precision)
-        x = layer_fn(config, p, chip, x, precision)
-        del chip
+    x = family(config).layers(config, params, x, deploy_key, precision)
     out = []
     for i, r in enumerate(rows):
-        xf = _rms(x[i, np.asarray(r)], params["ln_f"], config["rms_norm_eps"])
+        xf = rms(x[i, np.asarray(r)], params["ln_f"], config["rms_norm_eps"])
         out.append(np.asarray(contract("sd,dv->sv", xf, params["unembed"],
                                        precision)))
     return out

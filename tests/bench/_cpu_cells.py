@@ -64,10 +64,10 @@ def over_limit(numbers: dict, limits: dict) -> list:
 
 
 def run(config_name: str, seed: int, hook=None, mix: str = "smoke-mix",
-        limits: dict = None) -> dict:
+        limits: dict = None, seconds: float = 1.0) -> dict:
     bench = harness.Bench()
     return harness.run_cell(
-        bench, "smoke", seed, 1.0, False, t_start=time.perf_counter(),
+        bench, "smoke", seed, seconds, False, t_start=time.perf_counter(),
         hook=hook, config=_json(config_name), mix=_json(mix),
         check=limits or check(),
         metric_list=[m for m in bench.spec["end_to_end"]
@@ -106,11 +106,12 @@ def state_unchanged(engine):
 
 
 def half_batch(engine):
-    """The decode step runs only the first half of the slots; the others
-    keep their state and token."""
+    """The decode step runs every other slot only; the others keep their
+    state and token. (Every other, not the upper half: an open loop at
+    moderate load seldom fills the upper half of its slots.)"""
     def change(dec, params, pool):
         active = pool["active"]
-        half = jnp.arange(active.shape[0]) < active.shape[0] // 2
+        half = jnp.arange(active.shape[0]) % 2 == 0
         logits, pool = dec(params, dict(pool, active=active & half))
         return logits, dict(pool, active=active)
     _wrap_decode(engine, change)

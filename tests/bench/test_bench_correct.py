@@ -38,7 +38,11 @@ def _control(replayed, gaps_fn, seed):
     # 512 wide, head 128, 8 requests replayed, seed 11: share_off_best
     # reads 0 on the sound run and 0.483 on the control; the sound run
     # also passes the limit that codeqwen's cell commits
-    ("wide-codeqwen", "wide-mix", 11, "committed")])
+    ("wide-codeqwen", "wide-mix", 11, "committed"),
+    # the open-loop Poisson test mix (prompts 128-2048 in 32-row chunks)
+    # at 512 wide, against the limit codeqwen's cell commits too (at test
+    # widths the control reads as the sound run on its 80 tokens)
+    ("wide-codeqwen", "poisson-mix", 11, "committed")])
 def test_sound_run_correct_and_control_fails(name, mix, seed, limits,
                                              monkeypatch):
     replayed, gaps_fn = _spy(monkeypatch)

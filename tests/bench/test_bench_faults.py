@@ -17,3 +17,18 @@ def test_fault_comes_out_not_correct(name, fault):
     assert cells.over_limit(numbers, cells.check()), out["check"]
     committed = cells.committed_check(name)
     assert cells.over_limit(numbers, committed), (out["check"], committed)
+
+
+@pytest.mark.parametrize("fault", sorted(cells.FAULTS))
+def test_fault_on_the_open_loop_mix(fault):
+    """The same faults under the Poisson test mix (data/poisson-mix.json,
+    long prompts in 32-row chunks, a few slots of 16 live at a time), held
+    to the test cells' limits and to what codeqwen's cell commits."""
+    committed = cells.committed_check("smoke-codeqwen")
+    out = cells.run("smoke-codeqwen", 2**31 + 6, hook=cells.FAULTS[fault],
+                    mix="poisson-mix", seconds=2.0,
+                    limits=dict(cells.check(), **committed))
+    assert out["correct"] is False, out["check"]
+    numbers = {k: v["value"] for k, v in out["check"].items()}
+    assert cells.over_limit(numbers, cells.check()), out["check"]
+    assert cells.over_limit(numbers, committed), (out["check"], committed)

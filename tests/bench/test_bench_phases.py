@@ -80,7 +80,7 @@ def test_align_refuses_what_no_offset_fits():
 def test_innermost_pieces():
     spans = [("a", 0.0, 10.0), ("b", 2.0, 3.0), ("c", 5.0, 5.0),
              ("d", 6.0, 1.0), ("e", 12.0, 1.0)]
-    assert phases.innermost(spans) == [
+    assert devtrace.innermost(spans) == [
         (0.0, 2.0, "a"), (2.0, 5.0, "b"), (5.0, 6.0, "c"), (6.0, 7.0, "d"),
         (7.0, 10.0, "c"), (12.0, 13.0, "e")]
 
@@ -189,26 +189,28 @@ def small(tmp_path_factory):
     run = log_dir / "plugins" / "profile" / "run"
     run.mkdir(parents=True)
     (run / "host.xplane.pb").write_bytes(_xspace(data["planes"]))
-    return data, devtrace.load(str(log_dir)), phases.load(str(log_dir))
+    return data, devtrace.load(str(log_dir))
 
 
 def test_load_on_a_trimmed_chip_trace(small):
-    """phases.load() is devtrace.load() with the program spans and runs:
-    what the benchmark's readers get from devtrace is unchanged."""
-    data, old, new = small
-    assert set(new) == set(old) | {"program", "modules"}
-    assert all(new[k] == old[k] for k in old)
-    (plane,) = old["device"]
+    """devtrace.load() keeps the device's ops and program runs, the
+    benchmark's own spans and the program's `serve.*` spans apart."""
+    data, tr = small
+    assert set(tr) == {"device", "modules", "host", "program", "lines"}
+    (plane,) = tr["device"]
     recorded = data["planes"][plane][devtrace.OPS_LINE]
-    assert [e[0] for e in old["device"][plane]] == \
+    assert [e[0] for e in tr["device"][plane]] == \
         [n for n, _, _ in sorted(recorded["events"], key=lambda e: e[1])]
-    assert old["lines"] == {plane: sorted(data["planes"][plane])}
-    assert old["host"] and all(n.startswith(devtrace.SPAN_PREFIX)
-                               for n, _, _ in old["host"])
-    names = [n for n, _, _ in new["program"]]
-    assert all(n.startswith(phases.PROGRAM_PREFIX) for n in names)
-    assert [s for _, s, _ in new["program"]] == \
-        sorted(s for _, s, _ in new["program"])
+    assert [e[0] for e in tr["modules"][plane]] == [
+        n for n, _, _ in sorted(data["planes"][plane][
+            devtrace.MODULES_LINE]["events"], key=lambda e: e[1])]
+    assert tr["lines"] == {plane: sorted(data["planes"][plane])}
+    assert tr["host"] and all(n.startswith(devtrace.SPAN_PREFIX)
+                              for n, _, _ in tr["host"])
+    names = [n for n, _, _ in tr["program"]]
+    assert all(n.startswith(devtrace.PROGRAM_PREFIX) for n in names)
+    assert [s for _, s, _ in tr["program"]] == \
+        sorted(s for _, s, _ in tr["program"])
     assert {"serve.iter", "serve.schedule", "serve.prefill",
             "serve.dispatch.slot_prefill", "serve.prefill.wait",
             "serve.decode", "serve.dispatch.pool_decode",
@@ -221,7 +223,7 @@ def test_phases_on_a_trimmed_chip_trace(small):
     events read early), a planted shift moves it by exactly as much, the
     host's share of the idle time is part of the device's idle share, and
     host_ms_per_step reads the held iterations."""
-    data, _, tr = small
+    data, tr = small
     dev, prog = devtrace.device_events(tr), tr["program"]
     mods = phases.device_modules(tr)
     w = tuple(data["window"])
@@ -246,3 +248,24 @@ def test_phases_on_a_trimmed_chip_trace(small):
     got = harness.reader("host_ms_per_step")(_Ctx(_registry(prog)))
     assert got == pytest.approx(held / len(iters) * 1e-6)
     assert 0.5 < got < 10.0
+
+
+def test_idle_gaps_on_a_trimmed_chip_trace(small):
+    """The breakdown's idle gaps are named by the program's own spans,
+    with device times on the spans' clock (`phases.align`): on the chip's
+    trace the device idles through the decode readbacks, and every idle
+    nanosecond is in some gap."""
+    data, tr = small
+    dev, prog = devtrace.device_events(tr), tr["program"]
+    w = tuple(data["window"])
+    evs = devtrace.in_window(dev, w)
+    off, _ = phases.align(prog, phases.step_runs(phases.device_modules(tr),
+                                                 dev))
+    gaps = devtrace.idle_gaps(evs, prog, w, off)
+    names = {n for n, _ in gaps}
+    assert names <= {n for n, _, _ in prog} | {devtrace.NO_SPAN}
+    assert "serve.decode.readback" in names
+    idle = (w[1] - w[0] - devtrace.busy_ns(evs)) * 1e-9
+    assert sum(d for _, d in devtrace.idle_gaps(evs, prog, w, off,
+                                                k=10**6)) == \
+        pytest.approx(idle)

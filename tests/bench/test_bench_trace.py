@@ -10,12 +10,16 @@ def test_union_busy_and_gaps_by_hand():
     evs = [("a", 0.0, 10.0), ("b", 5.0, 10.0), ("c", 20.0, 5.0)]
     assert devtrace.union((s, s + d) for _, s, d in evs) == [(0, 15), (20, 25)]
     assert devtrace.busy_ns(evs) == 20.0
-    host = [("bench.window", 0.0, 40.0),
-            ("bench.engine.decode_step", 14.0, 5.0)]
-    gaps = devtrace.idle_gaps(evs, host, (0.0, 40.0))
-    assert [g[0] for g in gaps] == ["host: between engine calls",
-                                    "bench.engine.decode_step"]
+    host = [("bench.window", 0.0, 40.0)]
+    program = [("serve.iter", 14.0, 8.0), ("serve.decode.readback", 14.0,
+                                           5.0)]
+    gaps = devtrace.idle_gaps(evs, program, (0.0, 40.0))
+    assert [g[0] for g in gaps] == [devtrace.NO_SPAN,
+                                    "serve.decode.readback"]
     assert [g[1] for g in gaps] == pytest.approx([15e-9, 5e-9])
+    # device times read 3 ns early: the second gap moves under serve.iter
+    gaps = devtrace.idle_gaps(evs, program, (0.0, 40.0), offset=3.0)
+    assert [g[0] for g in gaps] == [devtrace.NO_SPAN, "serve.iter"]
     assert devtrace.top_ops(evs) == [["a", 10e-9], ["b", 10e-9],
                                      ["c", 5e-9]]
     assert devtrace.kernel_ns(evs, "^[ab]$") == (20.0, 2)
@@ -50,15 +54,17 @@ def test_reduction_on_a_trimmed_chip_trace():
         small = json.load(f)
     w = tuple(small["window"])
     evs = devtrace.in_window([tuple(e) for e in small["device"]], w)
+    # the spans the benchmark recorded around the engine's calls then
     host = [tuple(h) for h in small["host"]]
+    calls = [h for h in host if h[0] != devtrace.WINDOW_SPAN]
     assert all(" = " not in n for n, _, _ in evs)
     busy = devtrace.busy_ns(evs)
     assert 0.5 * (w[1] - w[0]) < busy < w[1] - w[0]
     assert all(not devtrace.WRAPPERS.match(n)
                for n, _ in devtrace.top_ops(evs))
-    gaps = devtrace.idle_gaps(evs, host, w)
+    gaps = devtrace.idle_gaps(evs, calls, w)
     assert gaps and all(g[0].startswith("bench.engine.") for g in gaps)
-    assert sum(g[1] for g in devtrace.idle_gaps(evs, host, w, k=10**6)) \
+    assert sum(g[1] for g in devtrace.idle_gaps(evs, calls, w, k=10**6)) \
         == pytest.approx((w[1] - w[0] - busy) * 1e-9)
     steps = [(s, s + d) for n, s, d in host
              if n == "bench.engine.decode_step" and w[0] <= s

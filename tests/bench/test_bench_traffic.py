@@ -95,3 +95,4 @@ def test_lognormal_median_and_clip():
 def test_negative_seed_refused():
     with pytest.raises(ValueError):
         traffic.seed_words(-1, 2)
+
